@@ -47,6 +47,30 @@ import sys
 import time
 
 
+#: JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: one fixed directory inside the checkout (listed in .gitignore).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is set here.  Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`, a fixed path, so a later run in the same
+    checkout finds what an earlier one compiled.  Every compilation is
+    kept, however short: the RST kernels compile in about a second.
+    """
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def _timed(fn):
     t0 = time.perf_counter()
     out = fn()
@@ -182,8 +206,10 @@ def bench_table3_resources():
 
 
 def bench_tpu_rst_kernel(quick=False):
-    """TPU-native RST engines (interpret mode): checksum-validated
-    bandwidth samples for sequential vs strided traversals."""
+    """TPU-native RST engines (compiled on a TPU, interpreted elsewhere):
+    checksum-validated bandwidth samples for sequential vs strided
+    traversals."""
+    import jax
     import jax.numpy as jnp
 
     from repro.core.params import RSTParams
@@ -198,8 +224,9 @@ def bench_tpu_rst_kernel(quick=False):
         sample, dt = _timed(
             lambda p=p: ops.measure_read_bandwidth(p, dtype=jnp.float32))
         rows.append((f"tpu_rst_read_{name}", dt,
-                     f"bytes={sample.bytes_moved};interp_gbps="
-                     f"{sample.gbps:.4f}"))
+                     f"bytes={sample.bytes_moved};"
+                     f"platform={jax.default_backend()};"
+                     f"gbps={sample.gbps:.4f}"))
     return rows
 
 
@@ -227,6 +254,35 @@ def bench_sweep_grid(quick=False):
              f"cache_hits={stats.cache_hits};max_gbps={max(gbps):.2f}")]
 
 
+def grid_ladder_axes(quick=False):
+    """The --grid ladder's HBM cross-product: 10,368 points at n=2^17
+    (864 at n=2^15 with `quick`).
+
+    Long streams are where batching pays: every lane is exactly periodic
+    (pow2 everything, no exclusive grants), so the compiled grid
+    evaluates a 2-window steady-state kernel per lane while the
+    per-point NumPy path expands all 2^17 commands.
+    """
+    from repro.core import HBM, RSTParams
+    from repro.core import timing_jax
+    from repro.core.address_mapping import policies_for
+
+    n = 1 << 15 if quick else 1 << 17
+    nparams = 6 if quick else 18
+    params = tuple(RSTParams(n=n, b=32, s=256 << (i % 6),
+                             w=(256 << (i % 6)) * (1 << (i // 6)))
+                   for i in range(nparams))
+    return timing_jax.GridAxes(
+        params=params,
+        policies=(None,) + tuple(policies_for(HBM))[:3],
+        ops=("read", "write", "duplex"),
+        num_engines=(1, 4) if quick else (1, 2, 4, 8),
+        arbitrations=((("round_robin", 1), ("burst", 4)) if quick else
+                      (("round_robin", 1), ("burst", 2), ("burst", 4),
+                       ("burst", 8))),
+        placements=("same_channel", "same_switch", "cross_switch"))
+
+
 def bench_grid(quick=False):
     """Grid-evaluation ladder (DESIGN.md §12): one policy x stride x op x
     engines x arbitration x placement cross-product priced four ways —
@@ -235,30 +291,13 @@ def bench_grid(quick=False):
     acceptance number (>= 100x on the >= 10k-point default grid).
     """
     import jax
-    from repro.core import HBM, RSTParams, get_mapping
+    from repro.core import HBM, get_mapping
     from repro.core import timing_jax, timing_model
-    from repro.core.address_mapping import policies_for
     from repro.launch.mesh import grid_mesh
 
     spec = HBM
-    # Long streams are where batching pays: every lane below is exactly
-    # periodic (pow2 everything, no exclusive grants), so the compiled
-    # grid evaluates a 2-window steady-state kernel per lane while the
-    # per-point NumPy path expands all 2^17 commands.
-    n = 1 << 15 if quick else 1 << 17
-    nparams = 6 if quick else 18
-    params = tuple(RSTParams(n=n, b=32, s=256 << (i % 6),
-                             w=(256 << (i % 6)) * (1 << (i // 6)))
-                   for i in range(nparams))
-    axes = timing_jax.GridAxes(
-        params=params,
-        policies=(None,) + tuple(policies_for(spec))[:3],
-        ops=("read", "write", "duplex"),
-        num_engines=(1, 4) if quick else (1, 2, 4, 8),
-        arbitrations=((("round_robin", 1), ("burst", 4)) if quick else
-                      (("round_robin", 1), ("burst", 2), ("burst", 4),
-                       ("burst", 8))),
-        placements=("same_channel", "same_switch", "cross_switch"))
+    axes = grid_ladder_axes(quick)
+    params = axes.params
 
     # Rung 1: the uncached naive path — one host-side NumPy evaluation
     # per point, timed on an evenly-spaced sample (the full product at
@@ -665,6 +704,7 @@ def main() -> None:
         if not os.access(json_dir, os.W_OK):
             ap.error(f"--json: directory {json_dir!r} is not writable")
 
+    setup_compile_cache()
     print("name,us_per_call,derived")
     if args.lint_report:
         suites = [bench_lint_report]

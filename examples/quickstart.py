@@ -1,7 +1,8 @@
 """Quickstart: the paper's tool + the framework around it, in 60 seconds.
 
 1. Benchmark the (simulated) U280 HBM with Shuhai — reproduces Table IV/V.
-2. Run the TPU-native RST Pallas engine (interpret mode on CPU).
+2. Run the TPU-native RST Pallas engine (compiled on a TPU, interpreted
+   elsewhere).
 3. Let the memory oracle pick a KV-cache layout (the technique acting as a
    framework feature).
 4. Forward + one training step of an assigned architecture (smoke size).
@@ -25,7 +26,7 @@ tot = camp.suite_total_throughput()
 print(f"Aggregate HBM throughput: {tot['total_gbps']:.0f} GB/s over "
       f"{tot['num_channels']} channels   (paper: 425 GB/s)")
 
-print("\n=== 2. TPU-native RST engine (Pallas, interpret mode) ===")
+print("\n=== 2. TPU-native RST engine (Pallas) ===")
 tile = ops.tile_bytes(jnp.float32)
 p = RSTParams(n=64, b=tile, s=tile, w=64 * tile)
 sample = ops.measure_read_bandwidth(p)

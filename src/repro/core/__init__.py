@@ -43,8 +43,8 @@ from repro.core.experiments import (Experiment, all_experiments,
                                     register_experiment, run_experiment)
 from repro.core.hwspec import (DDR3, DDR4, HBM, HBM3, TPU_V5E, ChipSpec,
                                MemorySpec, available_chips, available_specs,
-                               chip_by_name, register_chip, register_spec,
-                               spec_by_name)
+                               chip_by_name, chip_for_device, register_chip,
+                               register_spec, spec_by_name)
 from repro.core.latency import LatencyModule
 from repro.core.oracle import AccessPattern, MemoryOracle
 from repro.core.params import EngineRegisters, RSTParams
@@ -78,8 +78,8 @@ __all__ = [
     "Experiment", "all_experiments", "experiments_for", "get_experiment",
     "register_experiment", "run_experiment",
     "DDR3", "DDR4", "HBM", "HBM3", "TPU_V5E", "ChipSpec", "MemorySpec",
-    "available_chips", "available_specs", "chip_by_name", "register_chip",
-    "register_spec", "spec_by_name",
+    "available_chips", "available_specs", "chip_by_name", "chip_for_device",
+    "register_chip", "register_spec", "spec_by_name",
     "LatencyModule", "AccessPattern", "MemoryOracle",
     "EngineRegisters", "RSTParams",
     "addresses_jnp", "addresses_np", "block_params",

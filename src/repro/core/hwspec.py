@@ -24,7 +24,7 @@ on demand, mirroring how the paper reports "cycles" at the AXI clock.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 # ---------------------------------------------------------------------------
 # DRAM-side specs (paper platforms)
@@ -362,6 +362,8 @@ class ChipSpec:
     vmem_bytes: int               # on-chip vector memory
     ici_link_bandwidth: float     # B/s per link, per direction
     ici_links: int                # links per chip (2D torus on v5e)
+    # `jax.Device.device_kind` strings this entry describes.
+    device_kinds: Tuple[str, ...] = ()
 
     @property
     def ridge_intensity(self) -> float:
@@ -369,8 +371,8 @@ class ChipSpec:
         return self.peak_bf16_flops / self.hbm_bandwidth
 
 
-# Constants supplied with the assignment: 197 TFLOP/s bf16; 819 GB/s HBM;
-# ~50 GB/s/link ICI.
+# Published peaks (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s
+# bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of ICI (4 links x 50 GB/s).
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
@@ -379,6 +381,7 @@ TPU_V5E = ChipSpec(
     vmem_bytes=128 * 1024**2,
     ici_link_bandwidth=50e9,
     ici_links=4,
+    device_kinds=("TPU v5 lite",),
 )
 
 
@@ -410,6 +413,24 @@ def chip_by_name(name: str) -> ChipSpec:
         raise ValueError(
             f"unknown chip {name!r}; have {available_chips()}")
     return chip
+
+
+def chip_for_device(device=None) -> ChipSpec:
+    """The registered chip whose `device_kinds` holds `device.device_kind`
+    (default: ``jax.devices()[0]``).  The peaks a measurement on that
+    device is divided by; an unknown kind raises rather than borrowing
+    another chip's peaks."""
+    if device is None:
+        import jax  # deferred: the sim path stays jax-free
+        device = jax.devices()[0]
+    kind = device.device_kind
+    for chip in _CHIP_REGISTRY.values():
+        if kind in chip.device_kinds:
+            return chip
+    raise ValueError(
+        f"no registered chip covers device kind {kind!r} "
+        f"(platform {device.platform!r}); registered kinds: "
+        f"{ {c.name: c.device_kinds for c in _CHIP_REGISTRY.values()} }")
 
 
 register_chip(TPU_V5E)

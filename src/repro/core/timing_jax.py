@@ -39,6 +39,7 @@ points keep running through ``sim``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import itertools
@@ -50,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.address_mapping import AddressMapping, get_mapping
 from repro.core.engine import (PLACEMENTS, combine_placement,
@@ -606,6 +606,43 @@ _FULL_KERNEL_MAX_CMDS = 8192
 _LANE_SLOT_BUDGET = 1 << 21
 
 
+def _batch_columns(spec: MemorySpec, rows: Sequence[Dict[str, object]],
+                   nseg: int) -> Dict[str, np.ndarray]:
+    """Stack host rows (already padded to the lane count) into the
+    `_grid_kernel` operand: one [lanes] column per scalar, [lanes, nseg]
+    segment tables."""
+    lanes = len(rows)
+    cols: Dict[str, np.ndarray] = {}
+    for k in _I32:
+        cols[k] = np.array([r[k] for r in rows], dtype=np.int32)
+    for k in _I64:
+        cols[k] = np.array([r[k] for r in rows], dtype=np.int64)
+    for k in _F64:
+        cols[k] = np.array([r[k] for r in rows], dtype=np.float64)
+    cols["bf"] = np.array([r["b"] for r in rows], dtype=np.float64)
+    cols["eff"] = np.full(lanes, _efficiency(spec), dtype=np.float64)
+    seg = np.zeros((lanes, nseg, 5), dtype=np.int64)
+    for j, r in enumerate(rows):
+        for k, ent in enumerate(r["seg"]):
+            seg[j, k] = ent
+    cols["seg_pos"] = seg[:, :, 0]
+    cols["seg_mask"] = seg[:, :, 1]
+    cols["seg_row"] = seg[:, :, 2].astype(np.int32)
+    cols["seg_bg"] = seg[:, :, 3].astype(np.int32)
+    cols["seg_bank"] = seg[:, :, 4].astype(np.int32)
+    return cols
+
+
+def _to_host(out: Dict[str, jax.Array], n: int) -> Dict[str, np.ndarray]:
+    """Kernel outputs -> host arrays of the first `n` (real) lanes, plus
+    a "devices" column: how many devices the outputs were sharded over
+    (0 marks the NumPy fallback lanes, which run on the host)."""
+    ndev = len(out["gbps"].sharding.device_set)
+    host = {k: np.asarray(v)[:n] for k, v in out.items()}
+    host["devices"] = np.full(n, ndev)
+    return host
+
+
 def _run_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
                periodic: bool, mesh=None) -> Dict[str, np.ndarray]:
     """One batched kernel call over host rows -> dict of [len(rows)]
@@ -633,36 +670,14 @@ def _run_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
         ndev = int(np.prod(mesh.devices.shape))
         lanes += (-lanes) % ndev
 
-    cols: Dict[str, np.ndarray] = {}
-    pad = [rows[0]] * (lanes - n)
-    padded = list(rows) + pad
-    for k in _I32:
-        cols[k] = np.array([r[k] for r in padded], dtype=np.int32)
-    for k in _I64:
-        cols[k] = np.array([r[k] for r in padded], dtype=np.int64)
-    for k in _F64:
-        cols[k] = np.array([r[k] for r in padded], dtype=np.float64)
-    cols["bf"] = np.array([r["b"] for r in padded], dtype=np.float64)
-    cols["eff"] = np.full(lanes, _efficiency(spec), dtype=np.float64)
-    seg = np.zeros((lanes, nseg, 5), dtype=np.int64)
-    for j, r in enumerate(padded):
-        for k, ent in enumerate(r["seg"]):
-            seg[j, k] = ent
-    cols["seg_pos"] = seg[:, :, 0]
-    cols["seg_mask"] = seg[:, :, 1]
-    cols["seg_row"] = seg[:, :, 2].astype(np.int32)
-    cols["seg_bg"] = seg[:, :, 3].astype(np.int32)
-    cols["seg_bank"] = seg[:, :, 4].astype(np.int32)
-
+    cols = _batch_columns(spec, list(rows) + [rows[0]] * (lanes - n), nseg)
     kernel = _grid_kernel(spec, cap, nseg, periodic)
-    with enable_x64():
+    with jax.enable_x64(True):
         if mesh is not None:
             from repro.launch.mesh import shard_grid
             cols = {k: shard_grid(v, mesh, pad=False)[0]
                     for k, v in cols.items()}
-        out = kernel(cols)
-        out = {k: np.asarray(v)[:n] for k, v in out.items()}
-    return out
+        return _to_host(kernel(cols), n)
 
 
 _MIX_I32 = ("txns", "eng", "cmds", "bb", "excl")
@@ -722,14 +737,12 @@ def _run_mix_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
     cols["seg_bank"] = seg[:, :, 4].astype(np.int32)
 
     kernel = _mix_kernel(spec, cap, nseg, maxN)
-    with enable_x64():
+    with jax.enable_x64(True):
         if mesh is not None:
             from repro.launch.mesh import shard_grid
             cols = {k: shard_grid(v, mesh, pad=False)[0]
                     for k, v in cols.items()}
-        out = kernel(cols)
-        out = {k: np.asarray(v)[:n] for k, v in out.items()}
-    return out
+        return _to_host(kernel(cols), n)
 
 
 def _numpy_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
@@ -757,6 +770,7 @@ def _numpy_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
         out["queueing"][j] = res.queueing_delay_cycles
         out["head"][j] = res.detail["grant_head_wait_cycles"]
     out["bidx"] = out["bidx"].astype(np.int64)
+    out["devices"] = np.zeros(len(rows))
     return out
 
 
@@ -786,6 +800,7 @@ def _numpy_mix_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
         out["head"][j] = res.detail["grant_head_wait_cycles"]
         out["opsw"][j] = res.detail.get("op_switch_cycles", 0.0)
     out["bidx"] = out["bidx"].astype(np.int64)
+    out["devices"] = np.zeros(len(rows))
     return out
 
 
@@ -1179,6 +1194,12 @@ class GridResult:
     bound: np.ndarray
     queueing_delay_cycles: np.ndarray
     elapsed_seconds: float
+    #: Unit lanes per `_route` lane ("periodic"/"full"/"numpy"): lanes
+    #: on "numpy" ran on the host, not in the compiled kernel.
+    lanes_by_route: Dict[str, int]
+    #: Most devices any kernel output was sharded over (1 unsharded,
+    #: the mesh size under `mesh`, 0 if every lane ran on the host).
+    output_devices: int
     _builder: object = dataclasses.field(repr=False, compare=False)
 
     @property
@@ -1336,4 +1357,7 @@ def evaluate_grid(spec: MemorySpec, axes: GridAxes, *,
                       bound=bound.reshape(-1),
                       queueing_delay_cycles=queueing.reshape(-1),
                       elapsed_seconds=time.perf_counter() - t0,
+                      lanes_by_route=dict(collections.Counter(
+                          _route(r) for r in unit_rows)),
+                      output_devices=int(out["devices"].max()),
                       _builder=build)

@@ -5,8 +5,9 @@ packs :class:`repro.core.params.RSTParams` (byte-level, as the host thinks
 of them) into the scalar-prefetch operand (tile-level, as the engine
 consumes them) and runs the kernels.  ``measure_read_bandwidth`` is what the
 `pallas` backend of core/engine.py calls; on a real TPU the wall-clock
-number is the achieved HBM bandwidth of one core's engine, on CPU
-(interpret=True) it validates correctness only.
+number is the achieved HBM bandwidth of one core's engine, on any other
+platform the kernels run in interpret mode (`rst_read.interpret_mode`)
+and validate correctness only.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ from repro.core.params import RSTParams
 from repro.core.rst import block_params
 from repro.core.timing_model import _grant_beats
 from repro.kernels.rst_contend import rst_contend_mix_read, rst_contend_read
-from repro.kernels.rst_read import LANE, SUBLANE, rst_read
+from repro.kernels.rst_read import LANE, SUBLANE, interpret_mode, rst_read
 from repro.kernels.rst_write import rst_write
 
 
@@ -53,11 +54,11 @@ def grid_bucket(n_txns: int, floor: int = 16) -> int:
     return max(floor, 1 << (n_txns - 1).bit_length())
 
 
-def default_grid(n_txns: int, interpret: bool) -> int:
+def default_grid(n_txns: int) -> int:
     """Grid the measure_* wrappers use when the caller passes none:
     bucketed in interpret mode (compile sharing; gbps is validation-only),
     exact in compiled mode (gbps is a real measurement)."""
-    return grid_bucket(n_txns) if interpret else n_txns
+    return grid_bucket(n_txns) if interpret_mode() else n_txns
 
 
 _INT32_MAX = 2 ** 31 - 1
@@ -132,18 +133,15 @@ class BandwidthSample:
 
 def measure_read_bandwidth(p: RSTParams, *, dtype=jnp.float32,
                            burst_rows: int = SUBLANE,
-                           grid_txns: int | None = None,
-                           interpret: bool = True) -> BandwidthSample:
-    grid = grid_txns or default_grid(p.n, interpret)
+                           grid_txns: int | None = None) -> BandwidthSample:
+    grid = grid_txns or default_grid(p.n)
     operand = params_operand(p, dtype, burst_rows, grid)
     buf = make_working_buffer(p, dtype)
-    # Warm-up compiles and (in interpret mode) validates tracing.
-    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                   interpret=interpret)
+    # Warm-up compiles, so the timed call below excludes compilation.
+    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     out.block_until_ready()
     t0 = time.perf_counter()
-    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                   interpret=interpret)
+    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     out.block_until_ready()
     dt = time.perf_counter() - t0
     return BandwidthSample(bytes_moved=min(p.n, grid) * p.b, seconds=dt,
@@ -183,8 +181,7 @@ def measure_contended_bandwidth(p: RSTParams, *, num_engines: int,
                                 burst_beats: int = 1,
                                 dtype=jnp.float32,
                                 burst_rows: int = SUBLANE,
-                                grid_txns: int | None = None,
-                                interpret: bool = True) -> BandwidthSample:
+                                grid_txns: int | None = None) -> BandwidthSample:
     """N read engines sharing one memory port (DESIGN.md §8/§9): the
     grant-interleaved traversal of `timing_model.contended_throughput`
     run on the device, at the requested arbitration granularity
@@ -194,20 +191,20 @@ def measure_contended_bandwidth(p: RSTParams, *, num_engines: int,
     wall time), so `gbps` is the port's *aggregate* under contention."""
     if num_engines < 1:
         raise ValueError(f"num_engines must be >= 1, got {num_engines}")
-    grid = grid_txns or default_grid(p.n, interpret)
+    grid = grid_txns or default_grid(p.n)
     bb = _resolve_grant_beats(arbitration, burst_beats, grid)
     operand = contended_params_operand(p, num_engines, dtype, burst_rows,
                                        grid, bb)
     buf = make_working_buffer(p, dtype, num_engines=num_engines)
-    # Warm-up compiles and (in interpret mode) validates tracing.
+    # Warm-up compiles, so the timed call below excludes compilation.
     out = rst_contend_read(operand, buf, grid_txns=grid,
                            num_engines=num_engines, burst_beats=bb,
-                           burst_rows=burst_rows, interpret=interpret)
+                           burst_rows=burst_rows)
     out.block_until_ready()
     t0 = time.perf_counter()
     out = rst_contend_read(operand, buf, grid_txns=grid,
                            num_engines=num_engines, burst_beats=bb,
-                           burst_rows=burst_rows, interpret=interpret)
+                           burst_rows=burst_rows)
     out.block_until_ready()
     dt = time.perf_counter() - t0
     return BandwidthSample(
@@ -286,8 +283,7 @@ def measure_contended_mix_bandwidth(mix: EngineMix, *,
                                     burst_beats: int = 1,
                                     dtype=jnp.float32,
                                     burst_rows: int = SUBLANE,
-                                    grid_txns: int | None = None,
-                                    interpret: bool = True) -> BandwidthSample:
+                                    grid_txns: int | None = None) -> BandwidthSample:
     """A heterogeneous mix of read engines sharing one memory port: the
     per-engine generalization of `measure_contended_bandwidth`.  A
     uniform mix delegates to the homogeneous wrapper outright (the same
@@ -307,21 +303,21 @@ def measure_contended_mix_bandwidth(mix: EngineMix, *,
         return measure_contended_bandwidth(
             p, num_engines=len(mix), arbitration=arbitration,
             burst_beats=burst_beats, dtype=dtype, burst_rows=burst_rows,
-            grid_txns=grid_txns, interpret=interpret)
-    grid = grid_txns or default_grid(max(p.n for p in mix.params), interpret)
+            grid_txns=grid_txns)
+    grid = grid_txns or default_grid(max(p.n for p in mix.params))
     bb = _resolve_grant_beats(arbitration, burst_beats, grid)
     table = mix_params_operand(mix, dtype, burst_rows, grid, burst_beats=bb)
     buf = make_mix_working_buffer(mix, dtype, burst_rows=burst_rows,
                                   grid_txns=grid)
-    # Warm-up compiles and (in interpret mode) validates tracing.
+    # Warm-up compiles, so the timed call below excludes compilation.
     out = rst_contend_mix_read(table, buf, grid_txns=grid,
                                num_engines=len(mix), burst_beats=bb,
-                               burst_rows=burst_rows, interpret=interpret)
+                               burst_rows=burst_rows)
     out.block_until_ready()
     t0 = time.perf_counter()
     out = rst_contend_mix_read(table, buf, grid_txns=grid,
                                num_engines=len(mix), burst_beats=bb,
-                               burst_rows=burst_rows, interpret=interpret)
+                               burst_rows=burst_rows)
     out.block_until_ready()
     dt = time.perf_counter() - t0
     return BandwidthSample(
@@ -331,14 +327,17 @@ def measure_contended_mix_bandwidth(mix: EngineMix, *,
 
 def measure_write_bandwidth(p: RSTParams, *, dtype=jnp.float32,
                             burst_rows: int = SUBLANE,
-                            grid_txns: int | None = None,
-                            interpret: bool = True) -> BandwidthSample:
-    grid = grid_txns or default_grid(p.n, interpret)
+                            grid_txns: int | None = None) -> BandwidthSample:
+    grid = grid_txns or default_grid(p.n)
     operand = params_operand(p, dtype, burst_rows, grid)
     buf = make_working_buffer(p, dtype)
+    # Warm-up compiles; rst_write donates its buffer, so warm it on a
+    # throwaway copy and keep `buf` for the timed run.
+    warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
+                     burst_rows=burst_rows)
+    warm.block_until_ready()
     t0 = time.perf_counter()
-    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                    interpret=interpret)
+    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     out.block_until_ready()
     dt = time.perf_counter() - t0
     return BandwidthSample(bytes_moved=min(p.n, grid) * p.b, seconds=dt,
@@ -347,30 +346,26 @@ def measure_write_bandwidth(p: RSTParams, *, dtype=jnp.float32,
 
 def measure_duplex_bandwidth(p: RSTParams, *, dtype=jnp.float32,
                              burst_rows: int = SUBLANE,
-                             grid_txns: int | None = None,
-                             interpret: bool = True) -> BandwidthSample:
+                             grid_txns: int | None = None) -> BandwidthSample:
     """Mixed read/write traffic: both RST engines traverse one working
     buffer (the paper's duplex mode, Sec. III-C-1 — read and write modules
     run concurrently on one channel).  Off-TPU the two kernels run back to
     back; bytes moved counts both directions (2·N·B over the wall time).
     """
-    grid = grid_txns or default_grid(p.n, interpret)
+    grid = grid_txns or default_grid(p.n)
     operand = params_operand(p, dtype, burst_rows, grid)
     buf = make_working_buffer(p, dtype)
     # Warm-up compiles both engines (rst_write donates, so warm it on a
-    # throwaway copy and keep `buf` alive for the timed run).
-    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                   interpret=interpret)
+    # throwaway copy and keep `buf` for the timed run).
+    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     chk.block_until_ready()
     warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
-                     burst_rows=burst_rows, interpret=interpret)
+                     burst_rows=burst_rows)
     warm.block_until_ready()
     t0 = time.perf_counter()
-    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                   interpret=interpret)
+    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     chk.block_until_ready()   # the write donates buf; finish reading first
-    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows,
-                    interpret=interpret)
+    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows)
     out.block_until_ready()
     dt = time.perf_counter() - t0
     return BandwidthSample(bytes_moved=2 * min(p.n, grid) * p.b, seconds=dt,

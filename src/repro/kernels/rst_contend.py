@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.rst_read import LANE, SUBLANE
+from repro.kernels.rst_read import LANE, SUBLANE, interpret_mode
 
 
 def _grant_position(j, params_ref):
@@ -141,12 +141,10 @@ def _rst_contend_mix_kernel(table_ref, buf_ref, out_ref, acc_ref):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("grid_txns", "num_engines", "burst_beats", "burst_rows",
-                     "interpret"))
+    static_argnames=("grid_txns", "num_engines", "burst_beats", "burst_rows"))
 def rst_contend_mix_read(table: jax.Array, buf: jax.Array, *, grid_txns: int,
                          num_engines: int, burst_beats: int = 1,
-                         burst_rows: int = SUBLANE,
-                         interpret: bool = True) -> jax.Array:
+                         burst_rows: int = SUBLANE) -> jax.Array:
     """Run a heterogeneous mix of grant-interleaved RST read engines.
 
     The per-engine generalization of `rst_contend_read`: instead of one
@@ -169,7 +167,6 @@ def rst_contend_mix_read(table: jax.Array, buf: jax.Array, *, grid_txns: int,
       num_engines: static engine count (== table rows - 1).
       burst_beats: static grant size, as in `rst_contend_read`.
       burst_rows: rows per burst tile.
-      interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns:
       float32[burst_rows, LANE] elementwise checksum of every tile read
@@ -204,18 +201,16 @@ def rst_contend_mix_read(table: jax.Array, buf: jax.Array, *, grid_txns: int,
         _rst_contend_mix_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((burst_rows, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(table, buf)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("grid_txns", "num_engines", "burst_beats", "burst_rows",
-                     "interpret"))
+    static_argnames=("grid_txns", "num_engines", "burst_beats", "burst_rows"))
 def rst_contend_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
                      num_engines: int, burst_beats: int = 1,
-                     burst_rows: int = SUBLANE,
-                     interpret: bool = True) -> jax.Array:
+                     burst_rows: int = SUBLANE) -> jax.Array:
     """Run N grant-interleaved RST read engines over `buf`.
 
     Args:
@@ -234,7 +229,6 @@ def rst_contend_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
         every rotation covers each engine; padded steps are gated out of
         the checksum.
       burst_rows: rows per burst tile.
-      interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns:
       float32[burst_rows, LANE] elementwise checksum of every tile read
@@ -268,5 +262,5 @@ def rst_contend_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
         _rst_contend_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((burst_rows, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(params, buf)

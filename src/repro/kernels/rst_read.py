@@ -27,6 +27,17 @@ LANE = 128          # TPU lane width
 SUBLANE = 8         # minimum sublane tile for f32
 
 
+def interpret_mode() -> bool:
+    """Whether the RST kernels run in Pallas interpret mode.
+
+    The platform decides, in this one place: compiled on a TPU, where the
+    wall-clock number is an HBM measurement, and interpreted everywhere
+    else, where the kernels validate correctness only.  No caller can ask
+    for the interpreter on a TPU.
+    """
+    return jax.default_backend() != "tpu"
+
+
 def _index_map(i, params_ref):
     """Block index of transaction i: base + (i * stride) mod wset.
 
@@ -56,10 +67,9 @@ def _rst_read_kernel(params_ref, buf_ref, out_ref, acc_ref):
         out_ref[...] = acc_ref[...]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("grid_txns", "burst_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("grid_txns", "burst_rows"))
 def rst_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
-             burst_rows: int = SUBLANE, interpret: bool = True) -> jax.Array:
+             burst_rows: int = SUBLANE) -> jax.Array:
     """Run the RST read engine over `buf`.
 
     Args:
@@ -68,7 +78,6 @@ def rst_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
       buf: the working buffer, shape (rows, LANE) with rows % burst_rows == 0.
       grid_txns: static grid size (max transactions of this engine image).
       burst_rows: rows per burst tile; burst bytes = burst_rows*LANE*itemsize.
-      interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns:
       float32[burst_rows, LANE] elementwise checksum of every tile read.
@@ -92,5 +101,5 @@ def rst_read(params: jax.Array, buf: jax.Array, *, grid_txns: int,
         _rst_read_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((burst_rows, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(params, buf)

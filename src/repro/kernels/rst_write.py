@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.rst_read import LANE, SUBLANE, _index_map
+from repro.kernels.rst_read import LANE, SUBLANE, _index_map, interpret_mode
 
 
 def _rst_write_kernel(params_ref, buf_ref, out_ref):
@@ -40,10 +40,10 @@ def _rst_write_kernel(params_ref, buf_ref, out_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("grid_txns", "burst_rows", "interpret"),
+    jax.jit, static_argnames=("grid_txns", "burst_rows"),
     donate_argnums=(1,))
 def rst_write(params: jax.Array, buf: jax.Array, *, grid_txns: int,
-              burst_rows: int = SUBLANE, interpret: bool = True) -> jax.Array:
+              burst_rows: int = SUBLANE) -> jax.Array:
     """Run the RST write engine over `buf` (donated), returning the new buf.
 
     params: int32[4] = (stride_blocks, wset_blocks, base_block, n_txns).
@@ -67,5 +67,5 @@ def rst_write(params: jax.Array, buf: jax.Array, *, grid_txns: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         input_output_aliases={1: 0},
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(params, buf)

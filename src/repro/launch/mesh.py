@@ -18,25 +18,16 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """`axis_types` only where jax has it (>= 0.5); on older jax every mesh
-    axis is Auto-typed already, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for elastic rungs / tests."""
+    """Arbitrary mesh for elastic rungs / tests; every axis Auto-typed."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(shape)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def data_axes(mesh) -> tuple:

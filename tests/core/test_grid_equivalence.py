@@ -13,8 +13,10 @@ for that point within the documented tolerances:
 Sharded-vs-unsharded equality runs in a subprocess so this process keeps
 seeing exactly one device (same pattern as tests/launch/test_launch.py).
 """
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -22,6 +24,8 @@ import pytest
 from repro.core import HBM, RSTParams, Sweep
 from repro.core import timing_jax as tj
 from repro.core.address_mapping import policies_for
+
+ROOT = Path(__file__).resolve().parents[2]
 
 MB = 1024**2
 
@@ -143,6 +147,12 @@ np.testing.assert_array_equal(sharded.bound, base.bound)
 np.testing.assert_allclose(sharded.queueing_delay_cycles,
                            base.queueing_delay_cycles,
                            rtol=1e-12, atol=1e-12)
+# The sharded kernel's outputs live on all 8 devices; every lane of this
+# grid runs in a compiled kernel, none on the host fallback.
+assert (base.output_devices, sharded.output_devices) == (1, 8)
+assert sharded.lanes_by_route == base.lanes_by_route
+assert sum(base.lanes_by_route.values()) == 27
+assert "numpy" not in base.lanes_by_route
 print("SHARDED_OK", base.size)
 """
 
@@ -154,8 +164,10 @@ def test_sharded_matches_unsharded_on_8_device_mesh():
     out = subprocess.run(
         [sys.executable, "-c", SHARDED_EQUALITY],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        env={"PYTHONPATH": str(ROOT / "src"),
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "HOME": os.environ.get("HOME", str(ROOT)),
+             "JAX_PLATFORMS": "cpu"},
+        cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SHARDED_OK" in out.stdout

@@ -152,3 +152,32 @@ def test_backend_agnostic_envelope():
     for plc in PLACEMENTS:
         assert jax_env.placement_gbps[plc] == pytest.approx(
             sim_env.placement_gbps[plc], rel=1e-6)
+
+
+# -- chip peaks are looked up by the device's kind --------------------------
+
+class _Device:
+    def __init__(self, kind, platform="tpu"):
+        self.device_kind, self.platform = kind, platform
+
+
+def test_chip_for_device_covers_v5e():
+    from repro.core import TPU_V5E, chip_for_device
+    assert chip_for_device(_Device("TPU v5 lite")) is TPU_V5E
+    assert TPU_V5E.hbm_bandwidth == 819e9
+
+
+@pytest.mark.parametrize("kind,platform", [
+    ("TPU v4", "tpu"), ("TPU v5", "tpu"), ("cpu", "cpu")])
+def test_chip_for_device_rejects_unknown_kind(kind, platform):
+    # An unknown device is an error, never another chip's peaks.
+    from repro.core import chip_for_device
+    with pytest.raises(ValueError, match="no registered chip"):
+        chip_for_device(_Device(kind, platform))
+
+
+def test_chip_for_device_defaults_to_the_first_jax_device():
+    # The tests run on the CPU (conftest.py), whose kind has no peaks.
+    from repro.core import chip_for_device
+    with pytest.raises(ValueError, match="'cpu'"):
+        chip_for_device()

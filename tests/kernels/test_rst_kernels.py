@@ -129,7 +129,7 @@ class TestGridBucketing:
         assert s1.bytes_moved == 17 * 4096
         assert s2.bytes_moved == 25 * 4096
 
-    def test_compiled_mode_defaults_to_exact_grid(self):
+    def test_compiled_mode_defaults_to_exact_grid(self, monkeypatch):
         """Off interpret mode the gbps number is a real measurement, and a
         bucketed grid would bias it low (excess steps are timed but not
         counted) — the default must stay the exact grid."""
@@ -137,8 +137,18 @@ class TestGridBucketing:
         operand_exact = ops.params_operand(p, jnp.float32, 8, 17)
         assert int(operand_exact[3]) == 17
         # The wrappers' grid choice: interpret buckets, compiled does not.
-        assert ops.default_grid(p.n, interpret=True) == 32
-        assert ops.default_grid(p.n, interpret=False) == 17
+        assert ops.default_grid(p.n) == 32
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert ops.default_grid(p.n) == 17
+
+    @pytest.mark.parametrize("platform,interpret", [
+        ("tpu", False), ("cpu", True), ("gpu", True)])
+    def test_interpret_mode_follows_platform(self, monkeypatch, platform,
+                                             interpret):
+        # The platform alone decides: a TPU never runs the interpreter.
+        from repro.kernels.rst_read import interpret_mode
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert interpret_mode() is interpret
 
     def test_bucketed_checksum_matches_ref(self):
         p = RSTParams(n=13, b=4096, s=8192, w=16 * 4096)   # grid bucket 16

@@ -1,8 +1,10 @@
 """Launch layer: rules, shapes, HLO parsing, and an 8-device mini dry-run."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import pytest
@@ -11,6 +13,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.hlo_analysis import collective_bytes
 from repro.launch.shapes import SHAPES, cell_is_runnable, input_specs
+
+ROOT = Path(__file__).resolve().parents[2]
 
 
 class TestShapes:
@@ -129,9 +133,7 @@ rules = train_lib.make_rules(cfg, mesh)
 rules.update({{k: None for k in
              ("heads", "act_heads", "kv_heads", "cache_heads", "vocab",
               "act_vocab", "mlp", "act_mlp", "experts", "expert_mlp")}})
-# jax.set_mesh landed after 0.4; `with mesh:` is the older ambient-mesh
-# context and NamedSharding carries the mesh explicitly everywhere below.
-with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+with jax.set_mesh(mesh):
     specs = model.param_specs()
     state = train_lib.abstract_state(model)
     s_shard = train_lib.state_shardings(specs, rules, mesh)
@@ -156,8 +158,10 @@ def test_mini_multipod_dryrun_smoke(arch):
     out = subprocess.run(
         [sys.executable, "-c", MINI_DRYRUN.format(arch=arch)],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        env={"PYTHONPATH": str(ROOT / "src"),
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "HOME": os.environ.get("HOME", str(ROOT)),
+             "JAX_PLATFORMS": "cpu"},
+        cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "PEAK" in out.stdout

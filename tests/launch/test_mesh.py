@@ -5,10 +5,15 @@ implicit reshapes: a grid whose leading axis does not divide the device
 count must either be padded by an explicitly-reported number of repeated
 rows, or rejected with the exact remainder — never silently truncated.
 """
+import os
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from repro.launch.mesh import grid_mesh, grid_padding, shard_grid
+
+ROOT = Path(__file__).resolve().parents[2]
 
 
 class TestGridPadding:
@@ -107,8 +112,10 @@ def test_remainder_on_real_8_device_mesh():
     out = subprocess.run(
         [sys.executable, "-c", MULTI_DEVICE_REMAINDER],
         capture_output=True, text=True, timeout=300,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        env={"PYTHONPATH": str(ROOT / "src"),
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "HOME": os.environ.get("HOME", str(ROOT)),
+             "JAX_PLATFORMS": "cpu"},
+        cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "REMAINDER_OK" in out.stdout
