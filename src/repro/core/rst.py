@@ -11,7 +11,6 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.params import RSTParams
@@ -25,7 +24,8 @@ def addresses_np(p: RSTParams, count: int | None = None) -> np.ndarray:
     return p.a + (i * p.s) % p.w
 
 
-def addresses_jnp(p: RSTParams, count: int) -> jnp.ndarray:
+def addresses_jnp(p: RSTParams, count: int) -> "jax.Array":
+    import jax.numpy as jnp  # deferred: keeps the sim path jax-free
     i = jnp.arange(count, dtype=jnp.int64)
     return p.a + (i * p.s) % p.w
 

@@ -24,6 +24,7 @@ import dataclasses
 import itertools
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from repro import spans
 from repro.core import timing_model
 from repro.core.engine import Engine, get_backend
 from repro.core.engine_mix import EngineMix, normalize_mix
@@ -384,17 +385,21 @@ class Sweep:
 
     def run(self) -> List[SweepResult]:
         """Evaluate every queued point; results align with `points` order."""
-        if self.backend_impl.deterministic and getattr(
-                self.backend_impl, "supports_grid", False):
-            self._grid_prefill()
-        out: List[SweepResult] = []
-        for pt in self._points:
-            self.stats.points += 1
-            if pt.kind == KIND_THROUGHPUT:
-                value, cached = self._run_throughput(pt)
-            elif pt.kind == KIND_CONTENTION:
-                value, cached = self._run_contention(pt)
-            else:
-                value, cached = self._run_latency(pt)
-            out.append(SweepResult(point=pt, value=value, cached=cached))
-        return out
+        with spans.span("repro.sweep.run"):
+            if self.backend_impl.deterministic and getattr(
+                    self.backend_impl, "supports_grid", False):
+                with spans.span("repro.sweep.prefill"):
+                    self._grid_prefill()
+            with spans.span("repro.sweep.serve"):
+                out: List[SweepResult] = []
+                for pt in self._points:
+                    self.stats.points += 1
+                    if pt.kind == KIND_THROUGHPUT:
+                        value, cached = self._run_throughput(pt)
+                    elif pt.kind == KIND_CONTENTION:
+                        value, cached = self._run_contention(pt)
+                    else:
+                        value, cached = self._run_latency(pt)
+                    out.append(SweepResult(point=pt, value=value,
+                                           cached=cached))
+                return out

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import spans
 from repro.core.address_mapping import AddressMapping, get_mapping
 from repro.core.engine import (PLACEMENTS, combine_placement,
                                combine_placement_ports, placement_mix_slices,
@@ -670,9 +671,20 @@ def _run_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
         ndev = int(np.prod(mesh.devices.shape))
         lanes += (-lanes) % ndev
 
-    cols = _batch_columns(spec, list(rows) + [rows[0]] * (lanes - n), nseg)
-    kernel = _grid_kernel(spec, cap, nseg, periodic)
-    with jax.enable_x64(True):
+    with spans.span("repro.grid.columns"):
+        cols = _batch_columns(spec, list(rows) + [rows[0]] * (lanes - n),
+                              nseg)
+    return _dispatch(_grid_kernel(spec, cap, nseg, periodic), cols, mesh,
+                     "periodic" if periodic else "full", n)
+
+
+def _dispatch(kernel, cols: Dict[str, np.ndarray], mesh, route: str,
+              n: int) -> Dict[str, np.ndarray]:
+    """One device call of a grid kernel over padded columns, through the
+    copy of its first `n` lanes back to the host."""
+    lanes = len(cols["eff"])
+    with spans.span("repro.grid.dispatch", route=route, lanes=lanes,
+                    real=n), jax.enable_x64(True):
         if mesh is not None:
             from repro.launch.mesh import shard_grid
             cols = {k: shard_grid(v, mesh, pad=False)[0]
@@ -712,22 +724,34 @@ def _run_mix_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
         ndev = int(np.prod(mesh.devices.shape))
         lanes += (-lanes) % ndev
 
+    with spans.span("repro.grid.columns"):
+        cols = _mix_batch_columns(spec, list(rows) + [rows[0]] * (lanes - n),
+                                  nseg, maxN)
+    return _dispatch(_mix_kernel(spec, cap, nseg, maxN), cols, mesh,
+                     "mixfull", n)
+
+
+def _mix_batch_columns(spec: MemorySpec, rows: Sequence[Dict[str, object]],
+                       nseg: int, maxN: int) -> Dict[str, np.ndarray]:
+    """Stack mixed host rows (already padded to the lane count) into the
+    `_mix_kernel` operand, each engine stack padded to `maxN` with its
+    engine-0 entry."""
+    lanes = len(rows)
     cols: Dict[str, np.ndarray] = {}
-    padded = list(rows) + [rows[0]] * (lanes - n)
     for k in _MIX_I32:
-        cols[k] = np.array([r[k] for r in padded], dtype=np.int32)
+        cols[k] = np.array([r[k] for r in rows], dtype=np.int32)
     for k in _MIX_F64:
-        cols[k] = np.array([r[k] for r in padded], dtype=np.float64)
+        cols[k] = np.array([r[k] for r in rows], dtype=np.float64)
     for k, dt in _MIX_STACKS:
         arr = np.empty((lanes, maxN), dtype=dt)
-        for j, r in enumerate(padded):
+        for j, r in enumerate(rows):
             v = r[k]
             arr[j, :len(v)] = v
             arr[j, len(v):] = v[0]
         cols[k] = arr
     cols["eff"] = np.full(lanes, _efficiency(spec), dtype=np.float64)
     seg = np.zeros((lanes, nseg, 5), dtype=np.int64)
-    for j, r in enumerate(padded):
+    for j, r in enumerate(rows):
         for k, ent in enumerate(r["seg"]):
             seg[j, k] = ent
     cols["seg_pos"] = seg[:, :, 0]
@@ -735,14 +759,7 @@ def _run_mix_batch(spec: MemorySpec, rows: Sequence[Dict[str, object]],
     cols["seg_row"] = seg[:, :, 2].astype(np.int32)
     cols["seg_bg"] = seg[:, :, 3].astype(np.int32)
     cols["seg_bank"] = seg[:, :, 4].astype(np.int32)
-
-    kernel = _mix_kernel(spec, cap, nseg, maxN)
-    with jax.enable_x64(True):
-        if mesh is not None:
-            from repro.launch.mesh import shard_grid
-            cols = {k: shard_grid(v, mesh, pad=False)[0]
-                    for k, v in cols.items()}
-        return _to_host(kernel(cols), n)
+    return cols
 
 
 def _numpy_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
@@ -754,21 +771,23 @@ def _numpy_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
     keys = ("gbps", "bidx", "issue", "bank", "faw", "acts", "cmds_total",
             "mean_service", "queueing", "head")
     out = {k: np.empty(len(rows), dtype=np.float64) for k in keys}
-    for j, r in enumerate(rows):
-        p, mapping, op, count, arb, bb_req = r["unit"]
-        res = timing_model.contended_throughput(
-            p, mapping, spec, num_engines=count, op=op, arbitration=arb,
-            burst_beats=bb_req)
-        out["gbps"][j] = res.aggregate_gbps
-        out["bidx"][j] = _BOUND_NAMES.index(res.bound)
-        out["issue"][j] = res.detail["bus/ccd"]
-        out["bank"][j] = res.detail["bank"]
-        out["faw"][j] = res.detail["faw"]
-        out["acts"][j] = res.detail["total_acts"]
-        out["cmds_total"][j] = res.detail["txns"]
-        out["mean_service"][j] = res.detail["mean_service_cycles"]
-        out["queueing"][j] = res.queueing_delay_cycles
-        out["head"][j] = res.detail["grant_head_wait_cycles"]
+    with spans.span("repro.grid.numpy_lanes", route="numpy",
+                    lanes=len(rows)):
+        for j, r in enumerate(rows):
+            p, mapping, op, count, arb, bb_req = r["unit"]
+            res = timing_model.contended_throughput(
+                p, mapping, spec, num_engines=count, op=op,
+                arbitration=arb, burst_beats=bb_req)
+            out["gbps"][j] = res.aggregate_gbps
+            out["bidx"][j] = _BOUND_NAMES.index(res.bound)
+            out["issue"][j] = res.detail["bus/ccd"]
+            out["bank"][j] = res.detail["bank"]
+            out["faw"][j] = res.detail["faw"]
+            out["acts"][j] = res.detail["total_acts"]
+            out["cmds_total"][j] = res.detail["txns"]
+            out["mean_service"][j] = res.detail["mean_service_cycles"]
+            out["queueing"][j] = res.queueing_delay_cycles
+            out["head"][j] = res.detail["grant_head_wait_cycles"]
     out["bidx"] = out["bidx"].astype(np.int64)
     out["devices"] = np.zeros(len(rows))
     return out
@@ -784,21 +803,23 @@ def _numpy_mix_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]]
     keys = ("gbps", "bidx", "issue", "bank", "faw", "acts", "cmds_total",
             "mean_service", "queueing", "head", "opsw")
     out = {k: np.empty(len(rows), dtype=np.float64) for k in keys}
-    for j, r in enumerate(rows):
-        mix, mapping, arb, bb_req = r["mix_unit"]
-        res = timing_model.contended_throughput_mix(
-            mix, mapping, spec, arbitration=arb, burst_beats=bb_req)
-        out["gbps"][j] = res.aggregate_gbps
-        out["bidx"][j] = _BOUND_NAMES.index(res.bound)
-        out["issue"][j] = res.detail["bus/ccd"]
-        out["bank"][j] = res.detail["bank"]
-        out["faw"][j] = res.detail["faw"]
-        out["acts"][j] = res.detail["total_acts"]
-        out["cmds_total"][j] = res.detail["txns"]
-        out["mean_service"][j] = res.detail["mean_service_cycles"]
-        out["queueing"][j] = res.queueing_delay_cycles
-        out["head"][j] = res.detail["grant_head_wait_cycles"]
-        out["opsw"][j] = res.detail.get("op_switch_cycles", 0.0)
+    with spans.span("repro.grid.numpy_lanes", route="mixnumpy",
+                    lanes=len(rows)):
+        for j, r in enumerate(rows):
+            mix, mapping, arb, bb_req = r["mix_unit"]
+            res = timing_model.contended_throughput_mix(
+                mix, mapping, spec, arbitration=arb, burst_beats=bb_req)
+            out["gbps"][j] = res.aggregate_gbps
+            out["bidx"][j] = _BOUND_NAMES.index(res.bound)
+            out["issue"][j] = res.detail["bus/ccd"]
+            out["bank"][j] = res.detail["bank"]
+            out["faw"][j] = res.detail["faw"]
+            out["acts"][j] = res.detail["total_acts"]
+            out["cmds_total"][j] = res.detail["txns"]
+            out["mean_service"][j] = res.detail["mean_service_cycles"]
+            out["queueing"][j] = res.queueing_delay_cycles
+            out["head"][j] = res.detail["grant_head_wait_cycles"]
+            out["opsw"][j] = res.detail.get("op_switch_cycles", 0.0)
     out["bidx"] = out["bidx"].astype(np.int64)
     out["devices"] = np.zeros(len(rows))
     return out
@@ -824,24 +845,25 @@ def _run_rows(spec: MemorySpec, rows: Sequence[Dict[str, object]],
     arrays."""
     n = len(rows)
     merged: Dict[str, np.ndarray] = {}
-    for route in ("full", "periodic", "numpy", "mixfull", "mixnumpy"):
-        idxs = [j for j in range(n) if _route(rows[j]) == route]
-        if not idxs:
-            continue
-        sub = [rows[j] for j in idxs]
-        if route == "numpy":
-            out = _numpy_rows(spec, sub)
-        elif route == "mixnumpy":
-            out = _numpy_mix_rows(spec, sub)
-        elif route == "mixfull":
-            out = _run_mix_batch(spec, sub, mesh)
-        else:
-            out = _run_batch(spec, sub, route == "periodic", mesh)
-        for k, v in out.items():
-            if k not in merged:
-                dt = np.int64 if k == "bidx" else np.float64
-                merged[k] = np.empty(n, dtype=dt)
-            merged[k][idxs] = v
+    with spans.span("repro.grid.route"):
+        for route in ("full", "periodic", "numpy", "mixfull", "mixnumpy"):
+            idxs = [j for j in range(n) if _route(rows[j]) == route]
+            if not idxs:
+                continue
+            sub = [rows[j] for j in idxs]
+            if route == "numpy":
+                out = _numpy_rows(spec, sub)
+            elif route == "mixnumpy":
+                out = _numpy_mix_rows(spec, sub)
+            elif route == "mixfull":
+                out = _run_mix_batch(spec, sub, mesh)
+            else:
+                out = _run_batch(spec, sub, route == "periodic", mesh)
+            for k, v in out.items():
+                if k not in merged:
+                    dt = np.int64 if k == "bidx" else np.float64
+                    merged[k] = np.empty(n, dtype=dt)
+                merged[k][idxs] = v
     return merged
 
 
@@ -990,115 +1012,119 @@ def evaluate_points(spec: MemorySpec, reqs: Sequence[Tuple], *,
     model as ``Engine._contention_unscaled``; duplicate units across the
     batch evaluate once.  Returns result objects aligned with `reqs`.
     """
-    units: Dict[_Unit, int] = {}
-    plans: List[Tuple] = []
-    sw: Optional[SwitchModel] = None
-    for req in reqs:
-        if req[0] == "tp":
-            _, p, policy, op = req
-            unit: _Unit = (p.validate(spec), get_mapping(spec, policy),
-                           op, 1, "round_robin", 1)
-            units.setdefault(unit, len(units))
-            plans.append(("tp", unit, None))
-        elif req[0] == "cont":
-            if len(req) == 9:
-                _, p, policy, op, n_eng, arb, bb, placement, mix = req
-            else:
-                _, p, policy, op, n_eng, arb, bb, placement = req
-                mix = None
-            if n_eng < 1:
-                raise ValueError(
-                    f"num_engines must be >= 1, got {n_eng}")
-            mix, p, op, n_eng = normalize_mix(mix, p, op, n_eng)
-            p = p.validate(spec)
-            mapping = get_mapping(spec, policy)
-            if placement not in PLACEMENTS:
-                raise ValueError(f"unknown placement {placement!r}; "
-                                 f"valid: {PLACEMENTS}")
-            if mix is not None:
-                mix.validate(spec)
-                if placement == "same_channel":
-                    munit: _MixUnit = (mix, mapping, arb, bb)
-                    units.setdefault(munit, len(units))
-                    plans.append(("mix", munit, (arb, bb)))
-                    continue
-                sw = sw or _switch_for(spec)
-                effective, counts = placement_port_counts(
-                    sw, placement, n_eng)
-                ports = []
-                for lo, hi in placement_mix_slices(counts):
-                    sub = EngineMix.of(mix.entries[lo:hi])
-                    uni = sub.uniform_entry()
-                    if uni is not None:
-                        u = (uni[0], mapping, uni[1], len(sub), arb, bb)
+    with spans.span("repro.grid.evaluate"):
+        with spans.span("repro.grid.plan"):
+            units: Dict[_Unit, int] = {}
+            plans: List[Tuple] = []
+            sw: Optional[SwitchModel] = None
+            for req in reqs:
+                if req[0] == "tp":
+                    _, p, policy, op = req
+                    unit: _Unit = (p.validate(spec), get_mapping(spec, policy),
+                                   op, 1, "round_robin", 1)
+                    units.setdefault(unit, len(units))
+                    plans.append(("tp", unit, None))
+                elif req[0] == "cont":
+                    if len(req) == 9:
+                        _, p, policy, op, n_eng, arb, bb, placement, mix = req
                     else:
-                        u = (sub, mapping, arb, bb)
-                    units.setdefault(u, len(units))
-                    ports.append((hi - lo, u))
-                plans.append(("mixpl", ports, (n_eng, arb, bb, placement,
-                                               effective, mix)))
-                continue
-            if placement == "same_channel":
-                effective, counts = placement, [n_eng]
-            else:
-                sw = sw or _switch_for(spec)
-                effective, counts = placement_port_counts(
-                    sw, placement, n_eng)
-            cunits = {c: (p, mapping, op, c, arb, bb)
-                      for c in set(counts)}
-            for u in cunits.values():
-                units.setdefault(u, len(units))
-            plans.append(("cont", cunits, (n_eng, arb, bb, placement,
-                                           effective, counts)))
-        else:
-            raise ValueError(f"unknown request kind {req[0]!r}")
-    if not plans:
-        return []
-    ordered = sorted(units, key=units.get)
-    rows = [_mix_row(spec, u) if isinstance(u[0], EngineMix)
-            else _unit_row(spec, u) for u in ordered]
-    out = _run_rows(spec, rows, mesh)
-
-    results: List[object] = []
-    for plan in plans:
-        if plan[0] == "tp":
-            results.append(_tp_result(spec, rows, out, units[plan[1]]))
-            continue
-        if plan[0] == "mix":
-            munit, (arb, bb) = plan[1], plan[2]
-            results.append(_cont_result_mix(
-                spec, rows, out, units[munit], arb, bb))
-            continue
-        if plan[0] == "mixpl":
-            ports, (n_eng, arb, bb, placement, effective, mix) = \
-                plan[1], plan[2]
-            port_results = []
-            for count, u in ports:
-                jdx = units[u]
-                if isinstance(u[0], EngineMix):
-                    port_results.append(
-                        (count, _cont_result_mix(spec, rows, out, jdx,
-                                                 arb, bb)))
+                        _, p, policy, op, n_eng, arb, bb, placement = req
+                        mix = None
+                    if n_eng < 1:
+                        raise ValueError(
+                            f"num_engines must be >= 1, got {n_eng}")
+                    mix, p, op, n_eng = normalize_mix(mix, p, op, n_eng)
+                    p = p.validate(spec)
+                    mapping = get_mapping(spec, policy)
+                    if placement not in PLACEMENTS:
+                        raise ValueError(f"unknown placement {placement!r}; "
+                                         f"valid: {PLACEMENTS}")
+                    if mix is not None:
+                        mix.validate(spec)
+                        if placement == "same_channel":
+                            munit: _MixUnit = (mix, mapping, arb, bb)
+                            units.setdefault(munit, len(units))
+                            plans.append(("mix", munit, (arb, bb)))
+                            continue
+                        sw = sw or _switch_for(spec)
+                        effective, counts = placement_port_counts(
+                            sw, placement, n_eng)
+                        ports = []
+                        for lo, hi in placement_mix_slices(counts):
+                            sub = EngineMix.of(mix.entries[lo:hi])
+                            uni = sub.uniform_entry()
+                            if uni is not None:
+                                u = (uni[0], mapping, uni[1], len(sub), arb, bb)
+                            else:
+                                u = (sub, mapping, arb, bb)
+                            units.setdefault(u, len(units))
+                            ports.append((hi - lo, u))
+                        plans.append(("mixpl", ports, (n_eng, arb, bb, placement,
+                                                       effective, mix)))
+                        continue
+                    if placement == "same_channel":
+                        effective, counts = placement, [n_eng]
+                    else:
+                        sw = sw or _switch_for(spec)
+                        effective, counts = placement_port_counts(
+                            sw, placement, n_eng)
+                    cunits = {c: (p, mapping, op, c, arb, bb)
+                              for c in set(counts)}
+                    for u in cunits.values():
+                        units.setdefault(u, len(units))
+                    plans.append(("cont", cunits, (n_eng, arb, bb, placement,
+                                                   effective, counts)))
                 else:
-                    port_results.append(
-                        (count, _cont_result(spec, rows, out, jdx,
-                                             arb, bb)))
-            assert sw is not None
-            results.append(combine_placement_ports(
-                sw, placement, effective, n_eng, port_results,
-                arbitration=arb, burst_beats=bb, mix=mix))
-            continue
-        _, cunits, (n_eng, arb, bb, placement, effective, counts) = plan
-        per_count = {c: _cont_result(spec, rows, out, units[u], arb, bb)
-                     for c, u in cunits.items()}
-        if placement == "same_channel":
-            results.append(per_count[n_eng])
-        else:
-            assert sw is not None
-            results.append(combine_placement(
-                sw, placement, effective, n_eng, counts, per_count,
-                arbitration=arb, burst_beats=bb))
-    return results
+                    raise ValueError(f"unknown request kind {req[0]!r}")
+        if not plans:
+            return []
+        ordered = sorted(units, key=units.get)
+        with spans.span("repro.grid.rows"):
+            rows = [_mix_row(spec, u) if isinstance(u[0], EngineMix)
+                    else _unit_row(spec, u) for u in ordered]
+        out = _run_rows(spec, rows, mesh)
+
+        with spans.span("repro.grid.results"):
+            results: List[object] = []
+            for plan in plans:
+                if plan[0] == "tp":
+                    results.append(_tp_result(spec, rows, out, units[plan[1]]))
+                    continue
+                if plan[0] == "mix":
+                    munit, (arb, bb) = plan[1], plan[2]
+                    results.append(_cont_result_mix(
+                        spec, rows, out, units[munit], arb, bb))
+                    continue
+                if plan[0] == "mixpl":
+                    ports, (n_eng, arb, bb, placement, effective, mix) = \
+                        plan[1], plan[2]
+                    port_results = []
+                    for count, u in ports:
+                        jdx = units[u]
+                        if isinstance(u[0], EngineMix):
+                            port_results.append(
+                                (count, _cont_result_mix(spec, rows, out, jdx,
+                                                         arb, bb)))
+                        else:
+                            port_results.append(
+                                (count, _cont_result(spec, rows, out, jdx,
+                                                     arb, bb)))
+                    assert sw is not None
+                    results.append(combine_placement_ports(
+                        sw, placement, effective, n_eng, port_results,
+                        arbitration=arb, burst_beats=bb, mix=mix))
+                    continue
+                _, cunits, (n_eng, arb, bb, placement, effective, counts) = plan
+                per_count = {c: _cont_result(spec, rows, out, units[u], arb, bb)
+                             for c, u in cunits.items()}
+                if placement == "same_channel":
+                    results.append(per_count[n_eng])
+                else:
+                    assert sw is not None
+                    results.append(combine_placement(
+                        sw, placement, effective, n_eng, counts, per_count,
+                        arbitration=arb, burst_beats=bb))
+            return results
 
 
 # ------------------------------------------------------------- public: grid
@@ -1240,124 +1266,131 @@ def evaluate_grid(spec: MemorySpec, axes: GridAxes, *,
     :data:`REL_TOLERANCE` of the NumPy path (grid-equivalence tests).
     """
     t0 = time.perf_counter()
-    mappings = [get_mapping(spec, pol) for pol in axes.policies]
-    for op in axes.ops:
-        _direction_overheads(spec, op)   # validate ops eagerly
-    for arb, bb in axes.arbitrations:
-        _grant_beats(arb, bb, 1 << 30)   # validate pairs eagerly
-    for p in axes.params:
-        p.validate(spec)
+    with spans.span("repro.grid.evaluate"):
+        with spans.span("repro.grid.plan"):
+            mappings = [get_mapping(spec, pol) for pol in axes.policies]
+            for op in axes.ops:
+                _direction_overheads(spec, op)   # validate ops eagerly
+            for arb, bb in axes.arbitrations:
+                _grant_beats(arb, bb, 1 << 30)   # validate pairs eagerly
+            for p in axes.params:
+                p.validate(spec)
 
-    # Engine-counts needed per (N, placement), plus the per-port combine
-    # recipe for non-same_channel placements.
-    sw: Optional[SwitchModel] = None
-    recipes: Dict[Tuple[int, str], Tuple[str, List[int]]] = {}
-    needed = set()
-    for n in axes.num_engines:
-        for pl in axes.placements:
-            if pl == "same_channel":
-                recipes[(n, pl)] = (pl, [n])
-                needed.add(n)
-            else:
-                sw = sw or _switch_for(spec)
-                effective, counts = placement_port_counts(sw, pl, n)
-                recipes[(n, pl)] = (effective, counts)
-                needed.update(counts)
-    ucounts = sorted(needed)
-    cpos = {c: k for k, c in enumerate(ucounts)}
+            # Engine-counts needed per (N, placement), plus the per-port combine
+            # recipe for non-same_channel placements.
+            sw: Optional[SwitchModel] = None
+            recipes: Dict[Tuple[int, str], Tuple[str, List[int]]] = {}
+            needed = set()
+            for n in axes.num_engines:
+                for pl in axes.placements:
+                    if pl == "same_channel":
+                        recipes[(n, pl)] = (pl, [n])
+                        needed.add(n)
+                    else:
+                        sw = sw or _switch_for(spec)
+                        effective, counts = placement_port_counts(sw, pl, n)
+                        recipes[(n, pl)] = (effective, counts)
+                        needed.update(counts)
+            ucounts = sorted(needed)
+            cpos = {c: k for k, c in enumerate(ucounts)}
+            nunits = (len(axes.params) * len(mappings) * len(axes.ops)
+                      * len(ucounts) * len(axes.arbitrations))
 
-    # Unit grid: product(params, policies, ops, ucounts, arbitrations),
-    # one kernel lane each; host rows built per-axis, then broadcast.
-    unit_rows: List[Dict[str, object]] = []
-    for p, mapping, op, c, (arb, bb) in itertools.product(
-            axes.params, mappings, axes.ops, ucounts, axes.arbitrations):
-        unit_rows.append(_unit_row(spec, (p, mapping, op, c, arb, bb)))
-    out = _run_rows(spec, unit_rows, mesh)
+        # Unit grid: product(params, policies, ops, ucounts, arbitrations),
+        # one kernel lane each; host rows built per-axis, then broadcast.
+        with spans.span("repro.grid.rows"):
+            unit_rows = [
+                _unit_row(spec, (p, mapping, op, c, arb, bb))
+                for p, mapping, op, c, (arb, bb) in itertools.product(
+                    axes.params, mappings, axes.ops, ucounts,
+                    axes.arbitrations)]
+        out = _run_rows(spec, unit_rows, mesh)
 
-    # Map units onto points.  Unit flat index of (ip, ipol, iop, ic, ia):
-    # (((ip*npol + ipol)*nop + iop)*ncnt + ic)*narb + ia.
-    npm, npol, nop, nn, narb, npl = axes.shape
-    ncnt = len(ucounts)
-    ip = np.arange(npm).reshape(npm, 1, 1, 1, 1, 1)
-    ipol = np.arange(npol).reshape(1, npol, 1, 1, 1, 1)
-    iop = np.arange(nop).reshape(1, 1, nop, 1, 1, 1)
-    ia = np.arange(narb).reshape(1, 1, 1, 1, narb, 1)
-    base = (((ip * npol + ipol) * nop + iop) * ncnt)
-    bound_tbl = np.array(_BOUND_NAMES)
+        with spans.span("repro.grid.results"):
+            # Map units onto points.  Unit flat index of (ip, ipol, iop, ic, ia):
+            # (((ip*npol + ipol)*nop + iop)*ncnt + ic)*narb + ia.
+            npm, npol, nop, nn, narb, npl = axes.shape
+            ncnt = len(ucounts)
+            ip = np.arange(npm).reshape(npm, 1, 1, 1, 1, 1)
+            ipol = np.arange(npol).reshape(1, npol, 1, 1, 1, 1)
+            iop = np.arange(nop).reshape(1, 1, nop, 1, 1, 1)
+            ia = np.arange(narb).reshape(1, 1, 1, 1, narb, 1)
+            base = (((ip * npol + ipol) * nop + iop) * ncnt)
+            bound_tbl = np.array(_BOUND_NAMES)
 
-    gbps = np.empty(axes.shape, dtype=np.float64)
-    bound = np.empty(axes.shape, dtype=object)
-    queueing = np.empty(axes.shape, dtype=np.float64)
-    for j, n in enumerate(axes.num_engines):
-        for k, pl in enumerate(axes.placements):
-            effective, counts = recipes[(n, pl)]
-            if pl == "same_channel":
-                idx = ((base + cpos[n]) * narb + ia)[..., 0, :, 0]
-                gbps[:, :, :, j, :, k] = out["gbps"][idx]
-                bound[:, :, :, j, :, k] = bound_tbl[out["bidx"][idx]]
-                queueing[:, :, :, j, :, k] = out["queueing"][idx]
-                continue
-            # Per-port combine, vectorized over the sub-grid: the count
-            # multiset is fixed per (N, placement), so the capacity cap
-            # and dominant-port choice are, too (engine.combine_placement
-            # materializes the same recipe per point on results()).
-            mult = {c: counts.count(c) for c in set(counts)}
-            raw = np.zeros((npm, npol, nop, narb))
-            qsum = np.zeros((npm, npol, nop, narb))
-            for c, m in mult.items():
-                idxc = ((base + cpos[c]) * narb + ia)[..., 0, :, 0]
-                raw += m * out["gbps"][idxc]
-                qsum += m * c * out["queueing"][idxc]
-            dom = ((base + cpos[max(counts)]) * narb + ia)[..., 0, :, 0]
-            bnd = bound_tbl[out["bidx"][dom]].astype(object)
-            agg = raw.copy()
-            assert sw is not None
-            cap = sw.capacity_cap_gbps(effective)
-            if cap is not None:
-                capped = raw > cap
-                agg = np.where(capped, cap, raw)
-                lateral = sw.topology.lateral_gbps
-                name = ("lateral" if effective == "cross_switch"
-                        and lateral is not None and cap == lateral
-                        else "switch")
-                bnd = np.where(capped, name, bnd)
-            gbps[:, :, :, j, :, k] = agg
-            bound[:, :, :, j, :, k] = bnd
-            queueing[:, :, :, j, :, k] = qsum / n
+            gbps = np.empty(axes.shape, dtype=np.float64)
+            bound = np.empty(axes.shape, dtype=object)
+            queueing = np.empty(axes.shape, dtype=np.float64)
+            for j, n in enumerate(axes.num_engines):
+                for k, pl in enumerate(axes.placements):
+                    effective, counts = recipes[(n, pl)]
+                    if pl == "same_channel":
+                        idx = ((base + cpos[n]) * narb + ia)[..., 0, :, 0]
+                        gbps[:, :, :, j, :, k] = out["gbps"][idx]
+                        bound[:, :, :, j, :, k] = bound_tbl[out["bidx"][idx]]
+                        queueing[:, :, :, j, :, k] = out["queueing"][idx]
+                        continue
+                    # Per-port combine, vectorized over the sub-grid: the count
+                    # multiset is fixed per (N, placement), so the capacity cap
+                    # and dominant-port choice are, too (engine.combine_placement
+                    # materializes the same recipe per point on results()).
+                    mult = {c: counts.count(c) for c in set(counts)}
+                    raw = np.zeros((npm, npol, nop, narb))
+                    qsum = np.zeros((npm, npol, nop, narb))
+                    for c, m in mult.items():
+                        idxc = ((base + cpos[c]) * narb + ia)[..., 0, :, 0]
+                        raw += m * out["gbps"][idxc]
+                        qsum += m * c * out["queueing"][idxc]
+                    dom = ((base + cpos[max(counts)]) * narb + ia)[..., 0, :, 0]
+                    bnd = bound_tbl[out["bidx"][dom]].astype(object)
+                    agg = raw.copy()
+                    assert sw is not None
+                    cap = sw.capacity_cap_gbps(effective)
+                    if cap is not None:
+                        capped = raw > cap
+                        agg = np.where(capped, cap, raw)
+                        lateral = sw.topology.lateral_gbps
+                        name = ("lateral" if effective == "cross_switch"
+                                and lateral is not None and cap == lateral
+                                else "switch")
+                        bnd = np.where(capped, name, bnd)
+                    gbps[:, :, :, j, :, k] = agg
+                    bound[:, :, :, j, :, k] = bnd
+                    queueing[:, :, :, j, :, k] = qsum / n
 
-    def build() -> List[object]:
-        res: List[object] = []
-        for (ip_, p), (ipol_, pol), (iop_, op), (_, n), \
-                (ia_, (arb, bb)), (_, pl) in itertools.product(
-                enumerate(axes.params), enumerate(axes.policies),
-                enumerate(axes.ops), enumerate(axes.num_engines),
-                enumerate(axes.arbitrations), enumerate(axes.placements)):
-            del p, pol, op
+            def build() -> List[object]:
+                res: List[object] = []
+                for (ip_, p), (ipol_, pol), (iop_, op), (_, n), \
+                        (ia_, (arb, bb)), (_, pl) in itertools.product(
+                        enumerate(axes.params), enumerate(axes.policies),
+                        enumerate(axes.ops), enumerate(axes.num_engines),
+                        enumerate(axes.arbitrations), enumerate(axes.placements)):
+                    del p, pol, op
 
-            def uidx(c: int) -> int:
-                return ((((ip_ * npol + ipol_) * nop + iop_) * ncnt
-                         + cpos[c]) * narb + ia_)
+                    def uidx(c: int) -> int:
+                        return ((((ip_ * npol + ipol_) * nop + iop_) * ncnt
+                                 + cpos[c]) * narb + ia_)
 
-            if axes.kind == "throughput":
-                res.append(_tp_result(spec, unit_rows, out, uidx(1)))
-                continue
-            effective, counts = recipes[(n, pl)]
-            if pl == "same_channel":
-                res.append(_cont_result(spec, unit_rows, out, uidx(n),
-                                        arb, bb))
-                continue
-            per_count = {c: _cont_result(spec, unit_rows, out, uidx(c),
-                                         arb, bb) for c in set(counts)}
-            res.append(combine_placement(
-                _switch_for(spec), pl, effective, n, counts, per_count,
-                arbitration=arb, burst_beats=bb))
-        return res
+                    if axes.kind == "throughput":
+                        res.append(_tp_result(spec, unit_rows, out, uidx(1)))
+                        continue
+                    effective, counts = recipes[(n, pl)]
+                    if pl == "same_channel":
+                        res.append(_cont_result(spec, unit_rows, out, uidx(n),
+                                                arb, bb))
+                        continue
+                    per_count = {c: _cont_result(spec, unit_rows, out, uidx(c),
+                                                 arb, bb) for c in set(counts)}
+                    res.append(combine_placement(
+                        _switch_for(spec), pl, effective, n, counts, per_count,
+                        arbitration=arb, burst_beats=bb))
+                return res
 
-    return GridResult(spec=spec, axes=axes, gbps=gbps.reshape(-1),
-                      bound=bound.reshape(-1),
-                      queueing_delay_cycles=queueing.reshape(-1),
-                      elapsed_seconds=time.perf_counter() - t0,
-                      lanes_by_route=dict(collections.Counter(
-                          _route(r) for r in unit_rows)),
-                      output_devices=int(out["devices"].max()),
-                      _builder=build)
+            return GridResult(spec=spec, axes=axes, gbps=gbps.reshape(-1),
+                              bound=bound.reshape(-1),
+                              queueing_delay_cycles=queueing.reshape(-1),
+                              elapsed_seconds=time.perf_counter() - t0,
+                              lanes_by_route=dict(collections.Counter(
+                                  _route(r) for r in unit_rows)),
+                              output_devices=int(out["devices"].max()),
+                              _builder=build)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.engine_mix import EngineMix
 from repro.core.params import RSTParams
 from repro.core.rst import block_params
@@ -135,17 +136,25 @@ def measure_read_bandwidth(p: RSTParams, *, dtype=jnp.float32,
                            burst_rows: int = SUBLANE,
                            grid_txns: int | None = None) -> BandwidthSample:
     grid = grid_txns or default_grid(p.n)
-    operand = params_operand(p, dtype, burst_rows, grid)
-    buf = make_working_buffer(p, dtype)
-    # Warm-up compiles, so the timed call below excludes compilation.
-    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    out.block_until_ready()
-    t0 = time.perf_counter()
-    out = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return BandwidthSample(bytes_moved=min(p.n, grid) * p.b, seconds=dt,
-                           checksum=np.asarray(out))
+    nbytes = min(p.n, grid) * p.b
+    with spans.span("repro.ops.measure"):
+        operand = params_operand(p, dtype, burst_rows, grid)
+        with spans.span("repro.ops.buffer"):
+            buf = make_working_buffer(p, dtype)
+        # Warm-up compiles, so the timed call below excludes compilation.
+        with spans.span("repro.ops.warmup", kernel="rst_read"):
+            out = rst_read(operand, buf, grid_txns=grid,
+                           burst_rows=burst_rows)
+            out.block_until_ready()
+        with spans.span("repro.ops.timed", kernel="rst_read"):
+            t0 = time.perf_counter()
+            out = rst_read(operand, buf, grid_txns=grid,
+                           burst_rows=burst_rows)
+            out.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(out)
+    return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)
 
 
 def contended_params_operand(p: RSTParams, num_engines: int, dtype,
@@ -193,23 +202,28 @@ def measure_contended_bandwidth(p: RSTParams, *, num_engines: int,
         raise ValueError(f"num_engines must be >= 1, got {num_engines}")
     grid = grid_txns or default_grid(p.n)
     bb = _resolve_grant_beats(arbitration, burst_beats, grid)
-    operand = contended_params_operand(p, num_engines, dtype, burst_rows,
-                                       grid, bb)
-    buf = make_working_buffer(p, dtype, num_engines=num_engines)
-    # Warm-up compiles, so the timed call below excludes compilation.
-    out = rst_contend_read(operand, buf, grid_txns=grid,
-                           num_engines=num_engines, burst_beats=bb,
-                           burst_rows=burst_rows)
-    out.block_until_ready()
-    t0 = time.perf_counter()
-    out = rst_contend_read(operand, buf, grid_txns=grid,
-                           num_engines=num_engines, burst_beats=bb,
-                           burst_rows=burst_rows)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return BandwidthSample(
-        bytes_moved=num_engines * min(p.n, grid) * p.b, seconds=dt,
-        checksum=np.asarray(out))
+    nbytes = num_engines * min(p.n, grid) * p.b
+    with spans.span("repro.ops.measure"):
+        operand = contended_params_operand(p, num_engines, dtype,
+                                           burst_rows, grid, bb)
+        with spans.span("repro.ops.buffer"):
+            buf = make_working_buffer(p, dtype, num_engines=num_engines)
+        # Warm-up compiles, so the timed call below excludes compilation.
+        with spans.span("repro.ops.warmup", kernel="rst_contend_read"):
+            out = rst_contend_read(operand, buf, grid_txns=grid,
+                                   num_engines=num_engines, burst_beats=bb,
+                                   burst_rows=burst_rows)
+            out.block_until_ready()
+        with spans.span("repro.ops.timed", kernel="rst_contend_read"):
+            t0 = time.perf_counter()
+            out = rst_contend_read(operand, buf, grid_txns=grid,
+                                   num_engines=num_engines, burst_beats=bb,
+                                   burst_rows=burst_rows)
+            out.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(out)
+    return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)
 
 
 def _mix_block_rows(mix: EngineMix, dtype, burst_rows: int,
@@ -306,42 +320,55 @@ def measure_contended_mix_bandwidth(mix: EngineMix, *,
             grid_txns=grid_txns)
     grid = grid_txns or default_grid(max(p.n for p in mix.params))
     bb = _resolve_grant_beats(arbitration, burst_beats, grid)
-    table = mix_params_operand(mix, dtype, burst_rows, grid, burst_beats=bb)
-    buf = make_mix_working_buffer(mix, dtype, burst_rows=burst_rows,
-                                  grid_txns=grid)
-    # Warm-up compiles, so the timed call below excludes compilation.
-    out = rst_contend_mix_read(table, buf, grid_txns=grid,
-                               num_engines=len(mix), burst_beats=bb,
-                               burst_rows=burst_rows)
-    out.block_until_ready()
-    t0 = time.perf_counter()
-    out = rst_contend_mix_read(table, buf, grid_txns=grid,
-                               num_engines=len(mix), burst_beats=bb,
-                               burst_rows=burst_rows)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return BandwidthSample(
-        bytes_moved=sum(min(p.n, grid) * p.b for p in mix.params),
-        seconds=dt, checksum=np.asarray(out))
+    nbytes = sum(min(p.n, grid) * p.b for p in mix.params)
+    with spans.span("repro.ops.measure"):
+        table = mix_params_operand(mix, dtype, burst_rows, grid,
+                                   burst_beats=bb)
+        with spans.span("repro.ops.buffer"):
+            buf = make_mix_working_buffer(mix, dtype, burst_rows=burst_rows,
+                                          grid_txns=grid)
+        # Warm-up compiles, so the timed call below excludes compilation.
+        with spans.span("repro.ops.warmup", kernel="rst_contend_mix_read"):
+            out = rst_contend_mix_read(table, buf, grid_txns=grid,
+                                       num_engines=len(mix), burst_beats=bb,
+                                       burst_rows=burst_rows)
+            out.block_until_ready()
+        with spans.span("repro.ops.timed", kernel="rst_contend_mix_read"):
+            t0 = time.perf_counter()
+            out = rst_contend_mix_read(table, buf, grid_txns=grid,
+                                       num_engines=len(mix), burst_beats=bb,
+                                       burst_rows=burst_rows)
+            out.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(out)
+    return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)
 
 
 def measure_write_bandwidth(p: RSTParams, *, dtype=jnp.float32,
                             burst_rows: int = SUBLANE,
                             grid_txns: int | None = None) -> BandwidthSample:
     grid = grid_txns or default_grid(p.n)
-    operand = params_operand(p, dtype, burst_rows, grid)
-    buf = make_working_buffer(p, dtype)
-    # Warm-up compiles; rst_write donates its buffer, so warm it on a
-    # throwaway copy and keep `buf` for the timed run.
-    warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
-                     burst_rows=burst_rows)
-    warm.block_until_ready()
-    t0 = time.perf_counter()
-    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return BandwidthSample(bytes_moved=min(p.n, grid) * p.b, seconds=dt,
-                           checksum=np.asarray(out[:8]))
+    nbytes = min(p.n, grid) * p.b
+    with spans.span("repro.ops.measure"):
+        operand = params_operand(p, dtype, burst_rows, grid)
+        with spans.span("repro.ops.buffer"):
+            buf = make_working_buffer(p, dtype)
+        # Warm-up compiles; rst_write donates its buffer, so warm it on a
+        # throwaway copy and keep `buf` for the timed run.
+        with spans.span("repro.ops.warmup", kernel="rst_write"):
+            warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
+                             burst_rows=burst_rows)
+            warm.block_until_ready()
+        with spans.span("repro.ops.timed", kernel="rst_write"):
+            t0 = time.perf_counter()
+            out = rst_write(operand, buf, grid_txns=grid,
+                            burst_rows=burst_rows)
+            out.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(out[:8])
+    return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)
 
 
 def measure_duplex_bandwidth(p: RSTParams, *, dtype=jnp.float32,
@@ -353,20 +380,29 @@ def measure_duplex_bandwidth(p: RSTParams, *, dtype=jnp.float32,
     back; bytes moved counts both directions (2·N·B over the wall time).
     """
     grid = grid_txns or default_grid(p.n)
-    operand = params_operand(p, dtype, burst_rows, grid)
-    buf = make_working_buffer(p, dtype)
-    # Warm-up compiles both engines (rst_write donates, so warm it on a
-    # throwaway copy and keep `buf` for the timed run).
-    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    chk.block_until_ready()
-    warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
-                     burst_rows=burst_rows)
-    warm.block_until_ready()
-    t0 = time.perf_counter()
-    chk = rst_read(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    chk.block_until_ready()   # the write donates buf; finish reading first
-    out = rst_write(operand, buf, grid_txns=grid, burst_rows=burst_rows)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return BandwidthSample(bytes_moved=2 * min(p.n, grid) * p.b, seconds=dt,
-                           checksum=np.asarray(chk))
+    nbytes = 2 * min(p.n, grid) * p.b
+    with spans.span("repro.ops.measure"):
+        operand = params_operand(p, dtype, burst_rows, grid)
+        with spans.span("repro.ops.buffer"):
+            buf = make_working_buffer(p, dtype)
+        # Warm-up compiles both engines (rst_write donates, so warm it on a
+        # throwaway copy and keep `buf` for the timed run).
+        with spans.span("repro.ops.warmup", kernel="rst_duplex"):
+            chk = rst_read(operand, buf, grid_txns=grid,
+                           burst_rows=burst_rows)
+            chk.block_until_ready()
+            warm = rst_write(operand, jnp.array(buf), grid_txns=grid,
+                             burst_rows=burst_rows)
+            warm.block_until_ready()
+        with spans.span("repro.ops.timed", kernel="rst_duplex"):
+            t0 = time.perf_counter()
+            chk = rst_read(operand, buf, grid_txns=grid,
+                           burst_rows=burst_rows)
+            chk.block_until_ready()   # the write donates buf; read first
+            out = rst_write(operand, buf, grid_txns=grid,
+                            burst_rows=burst_rows)
+            out.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(chk)
+    return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)

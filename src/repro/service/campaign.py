@@ -11,8 +11,7 @@ and executes the planned grid on a coalescing
 * **retry** — transient backend failures (the taxonomy of
   core/engine.py) retry with deterministic exponential backoff + jitter
   on a *virtual* clock: delays are charged, never slept, so tests and
-  soak runs are exactly reproducible and sustained QPS is not an
-  artifact of sleeping;
+  soak runs are exactly reproducible;
 * **deadlines** — each request has a virtual-seconds budget; timeouts
   and backoffs consume it, and exhaustion degrades rather than hangs;
 * **circuit breakers** — per-backend; consecutive failures open the
@@ -35,11 +34,11 @@ re-evaluates 63 points, not 100.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core import _timing_reference as _reference
 from repro.core.address_mapping import get_mapping
 from repro.core.engine import (BackendTimeout, Engine,
@@ -124,7 +123,6 @@ class ServiceStats:
     quarantines: int = 0
     validated: int = 0                  # oracle checks run
     validation_mismatches: int = 0
-    sustained_qps: float = 0.0          # responses / wall-second, submit_all
 
     @property
     def dropped(self) -> int:
@@ -151,8 +149,7 @@ class CampaignService:
     disables degradation (capability gaps and exhausted budgets become
     `ok=False` responses instead).  All randomness (backoff jitter,
     validation sampling) comes from one seeded generator; all time is the
-    virtual clock `now` — the service is wall-clock-free except for the
-    `sustained_qps` statistic.
+    virtual clock `now` — the service reads no wall clock.
     """
 
     def __init__(self, primary: str = "sim",
@@ -188,7 +185,6 @@ class CampaignService:
         self._responses: Dict[ExperimentRequest, ServiceResponse] = {}
         self._oracle_cache: Dict[Tuple, Any] = {}
         self._engines: Dict[Tuple[str, int], Engine] = {}
-        self._wall_s = 0.0
 
     def breaker(self, backend: str) -> CircuitBreaker:
         return self._breakers[backend]
@@ -198,13 +194,14 @@ class CampaignService:
         """Serve one request: from the dedup cache, or by executing it."""
         self.stats.requests += 1
         cached = self._responses.get(request)
-        if cached is not None:
-            self.stats.deduped += 1
-            resp = dataclasses.replace(cached, request=request,
-                                       coalesced=True)
-        else:
-            resp = self._execute(request)
-            self._responses[request] = resp
+        with spans.span("repro.service.submit"):
+            if cached is not None:
+                self.stats.deduped += 1
+                resp = dataclasses.replace(cached, request=request,
+                                           coalesced=True)
+            else:
+                resp = self._execute(request)
+                self._responses[request] = resp
         if resp.ok:
             self.stats.completed += 1
         else:
@@ -213,26 +210,19 @@ class CampaignService:
 
     def submit_all(self, requests: Sequence[ExperimentRequest]
                    ) -> List[ServiceResponse]:
-        """Serve a batch; updates `stats.sustained_qps` from wall time
-        (the only wall-clock use in the service — reporting, not
-        behavior)."""
-        t0 = time.perf_counter()
-        out = [self.submit(r) for r in requests]
-        self._wall_s += time.perf_counter() - t0
-        if self._wall_s > 0:
-            self.stats.sustained_qps = (
-                (self.stats.completed + self.stats.failed) / self._wall_s)
-        return out
+        """Serve a batch, in order."""
+        return [self.submit(r) for r in requests]
 
     # ---------------------------------------------------------- execution
     def _execute(self, req: ExperimentRequest) -> ServiceResponse:
         start = self.now
         self.stats.executed += 1
         try:
-            exp = get_experiment(req.experiment)
-            spec = spec_by_name(req.spec)
-            planned, opts = plan_experiment(exp, spec, quick=req.quick,
-                                            **dict(req.overrides))
+            with spans.span("repro.service.plan"):
+                exp = get_experiment(req.experiment)
+                spec = spec_by_name(req.spec)
+                planned, opts = plan_experiment(exp, spec, quick=req.quick,
+                                                **dict(req.overrides))
         except (ValueError, TypeError) as e:
             return ServiceResponse(request=req, ok=False,
                                    error=f"bad request: {e}")
@@ -263,18 +253,21 @@ class CampaignService:
                 last_error = reason
                 break
 
-            outcome = self._attempt(spec, planned, backend_name, breaker,
-                                    deadline=start + self.deadline_s)
+            with spans.span("repro.service.attempt"):
+                outcome = self._attempt(spec, planned, backend_name, breaker,
+                                        deadline=start + self.deadline_s)
             attempts += outcome.attempts
             retries += outcome.retries
             if outcome.ok:
                 keyed = [(key, v) for (key, _), v in
                          zip(planned, outcome.values)]
-                result = exp.derive(spec, keyed, opts)
+                with spans.span("repro.service.derive"):
+                    result = exp.derive(spec, keyed, opts)
                 validated = None
                 if float(self._rng.random()) < self.validate_fraction:
-                    validated = self._validate(spec, planned,
-                                               outcome.values, impl)
+                    with spans.span("repro.service.validate"):
+                        validated = self._validate(spec, planned,
+                                                   outcome.values, impl)
                     if validated is False:
                         self.stats.validation_mismatches += 1
                         self.stats.quarantines += 1

@@ -289,6 +289,5 @@ class TestAcceptanceSoak:
             assert st.executed == len(mix)
             assert st.executed < st.requests
             assert st.deduped == 1000 - len(mix)
-            assert st.sustained_qps > 0
         finally:
             engine_mod._BACKEND_REGISTRY.pop("sim+soak", None)
