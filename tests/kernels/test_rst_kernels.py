@@ -1,10 +1,15 @@
 """Pallas RST engines vs pure-numpy oracles: shape/dtype sweep (interpret)."""
+import contextlib
+import functools
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
+from repro import spans
 from repro.core import RSTParams
 from repro.kernels import ops
 from repro.kernels.ref import rst_read_checksum_ref, rst_write_ref
@@ -426,3 +431,104 @@ class TestMixKernel:
         assert res.bound == "measured"
         assert res.mix == mix
         assert res.num_engines == 2
+
+
+def _read_ref(p, num_engines=1):
+    """The reference checksum of `p`'s stream on the working buffer, for
+    each of `num_engines` engines in its own window."""
+    buf = np.asarray(ops.make_working_buffer(p, jnp.float32,
+                                             num_engines=num_engines))
+    s, w = p.s // p.b, p.w // p.b
+    return sum(rst_read_checksum_ref(buf, s, w, k * w, p.n, 8)
+               for k in range(num_engines))
+
+
+def _write_ref(p):
+    """The first 8 rows of the working buffer after `p`'s write stream."""
+    buf = np.asarray(ops.make_working_buffer(p, jnp.float32))
+    return rst_write_ref(buf, p.s // p.b, p.w // p.b, 0, p.n, 8)[:8]
+
+
+class TestWarmOnce:
+    """A compiled RST program is warmed once per process: the first call
+    with a signature (kernel, static arguments, operand shapes) makes one
+    untimed call before the timed one, every later call only the timed
+    one, and no timed call compiles."""
+
+    P = RSTParams(n=12, b=4096, s=2 * 4096, w=8 * 4096)
+    CASES = {
+        # kernel, measure call, its reference, what makes a new signature
+        "read": ("rst_read", ops.measure_read_bandwidth, _read_ref,
+                 {"grid_txns": 32}),
+        "contend": ("rst_contend_read",
+                    functools.partial(ops.measure_contended_bandwidth,
+                                      num_engines=2),
+                    functools.partial(_read_ref, num_engines=2),
+                    {"arbitration": "burst", "burst_beats": 4}),
+        "write": ("rst_write", ops.measure_write_bandwidth, _write_ref,
+                  {"grid_txns": 32}),
+    }
+
+    @pytest.fixture
+    def fresh(self, monkeypatch):
+        """A process that has warmed no signature yet."""
+        monkeypatch.setattr(ops, "_WARMED", set())
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_a_repeated_signature_runs_one_kernel_call(self, monkeypatch,
+                                                       fresh, case):
+        name, measure, reference, change = self.CASES[case]
+        kernel = getattr(ops, name)
+        calls = []
+
+        def counted(*args, **kw):
+            calls.append(kw)
+            return kernel(*args, **kw)
+        monkeypatch.setattr(ops, name, counted)
+        first = measure(self.P, grid_txns=16)
+        assert len(calls) == 2                  # warm-up, then timed
+        second = measure(self.P, grid_txns=16)
+        assert len(calls) == 3                  # timed only
+        measure(self.P, **{"grid_txns": 16, **change})
+        assert len(calls) == 5                  # a new signature: both
+        assert np.array_equal(second.checksum, first.checksum)
+        np.testing.assert_allclose(second.checksum, reference(self.P),
+                                   rtol=1e-5)
+        assert second.bytes_moved == first.bytes_moved
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_no_compile_lands_in_a_timed_call(self, monkeypatch, fresh,
+                                              case):
+        _, measure, _, _ = self.CASES[case]
+        compiles, timed = [], []
+        span = spans.span
+
+        @contextlib.contextmanager
+        def recorded(name, **stats):
+            start = time.time()       # the clock of JAX's compile spans
+            with span(name, **stats):
+                yield
+            if name == "repro.ops.timed":
+                timed.append((start, time.time()))
+
+        def on_span(event, start, end, **_):
+            if event.startswith("/jax/core/compile/"):
+                compiles.append((start, end))
+        monkeypatch.setattr(spans, "span", recorded)
+        jax.clear_caches()
+        jax.monitoring.register_event_time_span_listener(on_span)
+        try:
+            counts = []
+            # first-seen signature, the same again, a new window size
+            for w in (8, 8, 16):
+                before = len(compiles)
+                measure(RSTParams(n=12, b=4096, s=4096, w=w * 4096),
+                        grid_txns=16)
+                counts.append(len(compiles) - before)
+        finally:
+            jax.monitoring.unregister_event_time_span_listener(on_span)
+        assert counts[0] > 0 and counts[2] > 0  # the listener hears them
+        assert counts[1] == 0
+        assert len(timed) == 3
+        assert not [(c, t) for c in compiles for t in timed
+                    if c[0] < t[1] and t[0] < c[1]]
