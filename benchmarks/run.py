@@ -89,15 +89,18 @@ BENCH_SPEC_NAMES = ("hbm", "ddr4")
 
 
 def resolve_experiments(names):
-    """Resolve a comma-separated experiment filter against the registry.
+    """Resolve a comma-separated experiment filter against the registry;
+    no filter means every experiment the sim backend serves (on HBM, whose
+    switch every experiment's hardware needs at most).
 
     Exits with a clear message (listing every registered name) instead of
     surfacing a traceback when a name is unknown.
     """
-    from repro.core.experiments import all_experiments, get_experiment
+    from repro.core.experiments import experiments_for, get_experiment
+    from repro.core.hwspec import HBM
 
     if not names:
-        return all_experiments()
+        return experiments_for(HBM)
     try:
         return [get_experiment(n.strip()) for n in names.split(",")]
     except ValueError as e:

@@ -17,7 +17,8 @@ import argparse
 import sys
 
 from repro.core import available_specs, spec_by_name
-from repro.core.experiments import (experiments_for, get_experiment,
+from repro.core.experiments import (backend_capability_gap,
+                                    experiments_for, get_experiment,
                                     run_experiment)
 
 
@@ -50,15 +51,19 @@ def main():
 
     rows = [("system", "experiment", "key", "value")]
     for spec in specs:
-        applicable = experiments_for(spec)
+        applicable = experiments_for(spec, args.backend)
         selected = applicable if wanted is None else wanted
         for exp in selected:
             if exp not in applicable:
                 # Explicitly requested but not runnable on this spec (e.g.
-                # a switch suite on DDR): report it like the backend skips
-                # below instead of silently producing no rows.
-                print(f"skipping {exp.name} on {spec.name}: needs an "
-                      f"inter-channel switch this spec does not have",
+                # a switch suite on DDR) or backend (a device-only
+                # experiment): report it like the backend skips below
+                # instead of silently producing no rows.
+                why = (backend_capability_gap(args.backend, exp.plan(
+                    spec, exp.options(quick=not args.full)))
+                       if exp.available_on(spec) else "needs an "
+                       "inter-channel switch this spec does not have")
+                print(f"skipping {exp.name} on {spec.name}: {why}",
                       file=sys.stderr)
                 continue
             try:

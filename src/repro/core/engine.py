@@ -304,6 +304,7 @@ class Backend:
     deterministic: bool = False
     supports_latency: bool = False
     supports_contention: bool = False
+    supports_gather: bool = False
 
     def throughput(self, spec: MemorySpec, p: RSTParams,
                    mapping: AddressMapping, *,
@@ -333,6 +334,15 @@ class Backend:
             f"backend {self.name!r} has no multi-engine contention path "
             f"(supports_contention=False); use the sim backend or the "
             f"pallas concurrent-access kernel (DESIGN.md §8)")
+
+    def gather_throughput(self, spec: MemorySpec, step
+                          ) -> timing_model.ThroughputResult:
+        """One decode step's table-driven reads (a
+        `core.decode_traffic.GatherStep`), measured on a device."""
+        raise UnsupportedCapability(
+            f"backend {self.name!r} has no gather engine "
+            f"(supports_gather=False); decode-step gathers are measured "
+            f"on the pallas backend's rst_gather kernel only")
 
 
 class SimBackend(Backend):
@@ -376,13 +386,16 @@ class PallasBackend(Backend):
     the DRAM address-mapping policy is the device's own, so `mapping` is
     ignored.  Latency raises: real accelerators expose no per-transaction
     timers — use ops.measure_read_bandwidth with N=1 as a coarse probe, or
-    the sim backend (DESIGN.md §2).
+    the sim backend (DESIGN.md §2).  Gathers (``gather_throughput``) read
+    a decode step's blocks through tables (kernels/rst_gather.py,
+    ops.measure_gather_bandwidth): the only backend that has them.
     """
 
     name = "pallas"
     deterministic = False
     supports_latency = False
     supports_contention = True
+    supports_gather = True
 
     def throughput(self, spec, p, mapping, *, op="read"):
         del spec, mapping  # the device's controller, not the model's
@@ -458,6 +471,18 @@ class PallasBackend(Backend):
                     "bytes": float(sample.bytes_moved)},
             arbitration=arbitration,
             burst_beats=burst_beats)
+
+    def gather_throughput(self, spec, step):
+        del spec  # the device's controller, not the model's
+        from repro.kernels import ops  # deferred: keeps sim path jax-free
+        sample = ops.measure_gather_bandwidth(step)
+        return timing_model.ThroughputResult(
+            gbps=sample.gbps, bound="measured",
+            detail={"seconds": sample.seconds,
+                    "bytes": float(sample.bytes_moved),
+                    "grid_steps": float(sample.grid_steps),
+                    "pad_steps": float(sample.pad_steps),
+                    "calls": float(sample.calls)})
 
 
 class JaxGridBackend(Backend):

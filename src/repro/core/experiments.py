@@ -49,7 +49,8 @@ from repro.core.hwspec import HBM, MemorySpec
 from repro.core.latency import LatencyModule
 from repro.core.params import RSTParams
 from repro.core.engine import get_backend
-from repro.core.sweep import (KIND_CONTENTION, KIND_LATENCY,
+from repro.core.decode_traffic import GatherStep, deployment
+from repro.core.sweep import (KIND_CONTENTION, KIND_GATHER, KIND_LATENCY,
                               KIND_THROUGHPUT, Sweep, SweepPoint)
 from repro.core.switch import PLACEMENTS, SwitchModel
 from repro.core.timing_model import (_contended_latency_delay,
@@ -157,8 +158,14 @@ def all_experiments() -> List[Experiment]:
     return list(_EXPERIMENT_REGISTRY.values())
 
 
-def experiments_for(spec: MemorySpec) -> List[Experiment]:
-    return [e for e in all_experiments() if e.available_on(spec)]
+def experiments_for(spec: MemorySpec, backend: str = "sim"
+                    ) -> List[Experiment]:
+    """The experiments a campaign on `backend` runs for `spec`: those the
+    spec has the hardware for and whose plan the backend can execute
+    (`backend_capability_gap`)."""
+    return [e for e in all_experiments() if e.available_on(spec)
+            and backend_capability_gap(backend,
+                                       e.plan(spec, e.options())) is None]
 
 
 def plan_experiment(experiment: "Experiment | str", spec: MemorySpec = HBM,
@@ -190,7 +197,8 @@ def backend_capability_gap(backend, planned: List[PlannedPoint]
 
     Serial-latency points need per-transaction timers
     (`supports_latency`, DESIGN.md §2); contention points need a
-    multi-engine path (`supports_contention`, DESIGN.md §8).  The
+    multi-engine path (`supports_contention`, DESIGN.md §8); gather
+    points need a table-driven engine (`supports_gather`).  The
     campaign service uses a non-None gap as a degradation trigger
     (pallas -> sim) instead of an error.
     """
@@ -204,6 +212,10 @@ def backend_capability_gap(backend, planned: List[PlannedPoint]
         return (f"needs multi-engine contention support, which backend "
                 f"{impl.name!r} does not provide "
                 f"(supports_contention=False)")
+    if not impl.supports_gather and any(
+            pt.kind == KIND_GATHER for _, pt in planned):
+        return (f"needs device gathers, which backend {impl.name!r} does "
+                f"not provide (supports_gather=False)")
     return None
 
 
@@ -222,9 +234,10 @@ def run_experiment(experiment: "Experiment | str", spec: MemorySpec = HBM,
                                     **options)
     gap = backend_capability_gap(backend, planned)
     if gap is not None:
-        raise ValueError(
-            f"experiment {exp.name!r} {gap}; use the sim backend "
-            f"(DESIGN.md §2/§8)")
+        remedy = ("the pallas backend" if any(
+            pt.kind == KIND_GATHER for _, pt in planned)
+            else "the sim backend (DESIGN.md §2/§8)")
+        raise ValueError(f"experiment {exp.name!r} {gap}; use {remedy}")
     sweep = Sweep(spec, backend)
     for _, pt in planned:
         sweep.add_point(pt)
@@ -1215,6 +1228,45 @@ register_experiment(Experiment(
 
 
 # ---------------------------------------------------------------------------
+# LLM decode traffic on the device's own memory (core/decode_traffic.py):
+# one decode step of a served model, read through gather tables by the
+# pallas backend's rst_gather engine.  No memory model has gathers, so
+# only the pallas backend serves it; the spec is not read.
+# ---------------------------------------------------------------------------
+
+
+def _decode_plan(spec, o):
+    dep = deployment(o["deployment"])
+    contexts = tuple(int(c) for c in o["contexts"])
+    dep.check_batch(contexts)
+    step = GatherStep(dep.name, int(o["seed"]), contexts, int(o["step"]))
+    return [(("decode_step", step.step),
+             SweepPoint(None, kind=KIND_GATHER, gather=step))]
+
+
+def _decode_derive(spec, keyed, o):
+    [(_, r)] = keyed
+    return {"gbps": r.gbps, **r.detail}
+
+
+register_experiment(Experiment(
+    name="decode_step",
+    artifact="LLM decode (device)",
+    title="One decode step's weight and KV-page gathers on the device HBM",
+    plan=_decode_plan,
+    derive=_decode_derive,
+    # The batch is the caller's; the defaults are the shortest contexts
+    # of each deployment's cell.
+    defaults={"deployment": "deepseek-v2-lite-ep8", "seed": 0,
+              "contexts": (16384,) * 16, "step": 0},
+    quick={"deployment": "deepseek-v2-lite-smoke", "contexts": (256,) * 4},
+    summarize=lambda spec, r: (f"gbps={r['gbps']:.2f};"
+                               f"bytes={r['bytes']:.0f}"),
+    flatten=lambda spec, r: [(k, f"{v:.6g}") for k, v in r.items()],
+))
+
+
+# ---------------------------------------------------------------------------
 # Experiment catalog (README.md section; `python -m benchmarks.run --catalog`)
 # ---------------------------------------------------------------------------
 
@@ -1223,17 +1275,10 @@ CATALOG_END = "<!-- experiment-catalog:end -->"
 
 
 def _catalog_backends(planned: List[PlannedPoint]) -> str:
-    """Backends that can execute a plan: serial-latency points need
-    per-transaction timers (sim only, DESIGN.md §2); contention points
-    need a multi-engine path (supports_contention, DESIGN.md §8)."""
+    """Backends that can execute a plan (`backend_capability_gap`)."""
     from repro.core.engine import available_backends
-    needs_latency = any(pt.kind == KIND_LATENCY for _, pt in planned)
-    needs_contention = any(pt.kind == KIND_CONTENTION for _, pt in planned)
-    names = [name for name in available_backends()
-             if (not needs_latency or get_backend(name).supports_latency)
-             and (not needs_contention
-                  or get_backend(name).supports_contention)]
-    return ", ".join(names)
+    return ", ".join(name for name in available_backends()
+                     if backend_capability_gap(name, planned) is None)
 
 
 def catalog_rows() -> List[Tuple[str, ...]]:
@@ -1245,6 +1290,8 @@ def catalog_rows() -> List[Tuple[str, ...]]:
         spec = next(s for s in specs if exp.available_on(s))
         planned = exp.plan(spec, exp.options())
         systems = ("switched specs" if exp.requires_switch
+                   else "the device's own (spec not read)" if any(
+                       pt.kind == KIND_GATHER for _, pt in planned)
                    else "all registered specs")
         rows.append((exp.name, exp.artifact,
                      f"{len(planned)} ({spec.name})",

@@ -26,6 +26,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro import spans
 from repro.core import timing_model
+from repro.core.decode_traffic import GatherStep
 from repro.core.engine import Engine, get_backend
 from repro.core.engine_mix import EngineMix, normalize_mix
 from repro.core.hwspec import HBM, MemorySpec
@@ -34,6 +35,7 @@ from repro.core.params import RSTParams
 KIND_THROUGHPUT = "throughput"
 KIND_LATENCY = "latency"
 KIND_CONTENTION = "contention"
+KIND_GATHER = "gather"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +64,7 @@ class SweepPoint:
     burst_beats: int = 1                    # beats per grant ("burst" only)
     placement: str = "same_channel"         # contention runs only
     mix: Optional[EngineMix] = None         # heterogeneous engine set (§13)
+    gather: Optional[GatherStep] = None     # gather points: params is None
 
     def __post_init__(self):
         if self.mix is None:
@@ -337,6 +340,19 @@ class Sweep:
             self.stats.evaluated += 1
         return trace, cached
 
+    def _run_gather(self, pt: SweepPoint) -> Tuple[object, bool]:
+        """A measurement, never memoized; with coalescing on, duplicate
+        points share one evaluation."""
+        key = ("gather", pt.gather)
+        cached, hit = self._flight_lookup(key)
+        if hit:
+            return cached, True
+        self.stats.evaluated += 1
+        res = self.backend_impl.gather_throughput(self.spec, pt.gather)
+        if self.coalesce:
+            self._flight[key] = res
+        return res, False
+
     def _grid_prefill(self) -> None:
         """Batch-evaluate every uncached deterministic throughput and
         contention point through the backend's grid path — one compiled
@@ -398,6 +414,8 @@ class Sweep:
                         value, cached = self._run_throughput(pt)
                     elif pt.kind == KIND_CONTENTION:
                         value, cached = self._run_contention(pt)
+                    elif pt.kind == KIND_GATHER:
+                        value, cached = self._run_gather(pt)
                     else:
                         value, cached = self._run_latency(pt)
                     out.append(SweepResult(point=pt, value=value,

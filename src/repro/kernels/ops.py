@@ -13,10 +13,16 @@ Each measure_* call times one kernel call.  The first call with a new
 signature (kernel, static arguments, operand shapes) in a process is
 preceded by one untimed call (`_warm_once`), so compilation is never
 timed.
+
+``measure_gather_bandwidth`` times one decode step of a deployment
+(core/decode_traffic.py): its table-driven `rst_gather` calls over a
+`DecodeArena`, the deployment's weights and page pool, which unlike the
+RST working buffers is built once a process and kept across requests.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Tuple
 
@@ -26,10 +32,13 @@ import numpy as np
 
 from repro import spans
 from repro.core.engine_mix import EngineMix
+from repro.core.decode_traffic import (DecodeDeployment, GatherStep,
+                                       deployment)
 from repro.core.params import RSTParams
 from repro.core.rst import block_params
 from repro.core.timing_model import _grant_beats
 from repro.kernels.rst_contend import rst_contend_mix_read, rst_contend_read
+from repro.kernels.rst_gather import rst_gather
 from repro.kernels.rst_read import LANE, SUBLANE, interpret_mode, rst_read
 from repro.kernels.rst_write import rst_write
 
@@ -145,19 +154,19 @@ class BandwidthSample:
 _WARMED: set = set()
 
 
-def _warm_once(name: str, kernel, params: jax.Array, buf: jax.Array,
-               **static) -> None:
+def _warm_once(name: str, kernel, *operands: jax.Array, **static) -> None:
     """Run `kernel` once, untimed, the first time this process calls it
     with this signature, so that no timed call includes compilation.
-    ``rst_write`` donates its buffer, so it is warmed on a copy."""
+    ``rst_write`` donates its buffer, the last operand, so it is warmed on
+    a copy."""
     key = (name, tuple(sorted(static.items())),
-           tuple((x.shape, x.dtype) for x in (params, buf)))
+           tuple((x.shape, x.dtype) for x in operands))
     if key in _WARMED:
         return
     with spans.span("repro.ops.warmup", kernel=name):
         if name == "rst_write":
-            buf = jnp.array(buf)
-        kernel(params, buf, **static).block_until_ready()
+            operands = operands[:-1] + (jnp.array(operands[-1]),)
+        kernel(*operands, **static).block_until_ready()
     _WARMED.add(key)
 
 
@@ -427,3 +436,132 @@ def measure_duplex_bandwidth(p: RSTParams, *, dtype=jnp.float32,
         with spans.span("repro.ops.checksum"):
             checksum = np.asarray(chk)
     return BandwidthSample(bytes_moved=nbytes, seconds=dt, checksum=checksum)
+
+
+# ------------------------------------------------------------------ decode
+ARENA_SALT = 0xA7E4A
+ARENA_MIX = 0x045D9F3B
+
+
+def arena_words(seed: int) -> np.ndarray:
+    """The arena formula's two uint32 words ``(m, c)`` for `seed`."""
+    m, c = np.random.default_rng([int(seed), ARENA_SALT]).integers(
+        0, 1 << 32, size=2, dtype=np.uint64)
+    return np.array([m | 1, c], dtype=np.uint32)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _arena_content(words: jax.Array, *, rows: int) -> jax.Array:
+    """Word ``f`` (row-major over the whole arena) holds the bits of
+    ``mix(m * f + c)``, where ``mix(h)`` is ``h ^= h >> 16; h *= ARENA_MIX;
+    h ^= h >> 16``, all in uint32 (mod 2^32): every bit of every word
+    depends on the seed and the word's place."""
+    row = jax.lax.broadcasted_iota(jnp.uint32, (rows, LANE), 0)
+    lane = jax.lax.broadcasted_iota(jnp.uint32, (rows, LANE), 1)
+    h = (row * LANE + lane) * words[0] + words[1]
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(ARENA_MIX)
+    h = h ^ (h >> 16)
+    return jax.lax.bitcast_convert_type(h, jnp.int32)
+
+
+@dataclasses.dataclass
+class DecodeArena:
+    """A deployment's weights and page pool on the device, for one seed:
+    built once a process and kept across requests (`decode_arena`)."""
+
+    deployment: DecodeDeployment
+    seed: int
+    array: jax.Array
+    warmed: set = dataclasses.field(default_factory=set)
+
+    @classmethod
+    def build(cls, dep: DecodeDeployment, seed: int) -> "DecodeArena":
+        if dep.arena_rows * LANE > 1 << 32:
+            raise ValueError(f"the arena of {dep.name!r} has more words "
+                             f"than uint32 counts")
+        with spans.span("repro.ops.arena"):
+            array = _arena_content(jnp.asarray(arena_words(seed)),
+                                   rows=dep.arena_rows)
+            array.block_until_ready()
+        return cls(dep, int(seed), array)
+
+    def warm(self, contexts: Tuple[int, ...]) -> None:
+        """Compile every grid a step of this batch can use."""
+        if contexts in self.warmed:
+            return
+        acc = jnp.zeros((SUBLANE, LANE), jnp.int32)
+        count = jnp.ones((1,), jnp.int32)
+        for rows, grid in sorted(self.deployment.grids(contexts)):
+            _warm_once("rst_gather", rst_gather, count,
+                       jnp.zeros((grid,), jnp.int32), acc, self.array,
+                       block_rows=rows)
+        self.warmed.add(contexts)
+
+
+# The arena this process holds: one at a time, as it fills most of a chip.
+_ARENA: list = []
+
+
+def decode_arena(dep: DecodeDeployment, seed: int) -> DecodeArena:
+    """The arena of `dep` and `seed`, built on first use; another
+    deployment or seed frees the one held before its own is built."""
+    if _ARENA and (_ARENA[0].deployment is dep
+                   and _ARENA[0].seed == int(seed)):
+        return _ARENA[0]
+    while _ARENA:
+        _ARENA.pop().array.delete()
+    _ARENA.append(DecodeArena.build(dep, seed))
+    return _ARENA[0]
+
+
+@dataclasses.dataclass(frozen=True)
+class GatherSample(BandwidthSample):
+    """One decode step's gathers: real blocks' bytes, the chained
+    checksum, and the counters of the engine calls."""
+
+    grid_steps: int = 0
+    pad_steps: int = 0
+    calls: int = 0
+
+
+def measure_gather_bandwidth(step: GatherStep) -> GatherSample:
+    """Run one decode step's gather calls, chained into one checksum, and
+    time them from the first launch to the last ``block_until_ready``."""
+    dep = deployment(step.deployment)
+    arena = decode_arena(dep, step.seed)
+    contexts = tuple(int(c) for c in step.contexts)
+    with spans.span("repro.ops.measure"):
+        calls = dep.plan(step.seed, contexts, step.step)
+        with spans.span("repro.ops.buffer"):
+            host = []
+            for call in calls:
+                n = len(call.blocks)
+                table = np.full(dep.grid(n), call.blocks[-1], np.int32)
+                table[:n] = call.blocks
+                host.append((np.array([n], np.int32), table))
+            # One transfer for the whole step: a transfer an array costs
+            # a host-to-device round trip each.
+            operands = jax.device_put(host)
+            acc = jnp.zeros((SUBLANE, LANE), jnp.int32)
+            jax.block_until_ready((acc, operands))
+        arena.warm(contexts)
+        with spans.span("repro.ops.timed", kernel="rst_gather"):
+            t0 = time.perf_counter()
+            for call, (count, table) in zip(calls, operands):
+                n = len(call.blocks)
+                with spans.span("repro.ops.gather", kind=call.kind,
+                                blocks=n, pad=table.shape[0] - n,
+                                block_bytes=call.block_bytes):
+                    acc = rst_gather(count, table, acc, arena.array,
+                                     block_rows=call.block_rows)
+            acc.block_until_ready()
+            dt = time.perf_counter() - t0
+        with spans.span("repro.ops.checksum"):
+            checksum = np.asarray(acc)
+    real = sum(len(c.blocks) for c in calls)
+    grid = sum(t.shape[0] for _, t in host)
+    return GatherSample(
+        bytes_moved=sum(len(c.blocks) * c.block_bytes for c in calls),
+        seconds=dt, checksum=checksum, grid_steps=grid,
+        pad_steps=grid - real, calls=len(calls))

@@ -18,7 +18,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import HBM, RSTParams, get_mapping, timing_jax
+from repro.core.decode_traffic import deployment
+from repro.kernels import ops
 from repro.kernels.rst_contend import rst_contend_mix_read, rst_contend_read
+from repro.kernels.rst_gather import rst_gather
 from repro.kernels.rst_read import LANE, SUBLANE, rst_read
 from repro.kernels.rst_write import rst_write
 
@@ -95,6 +98,31 @@ def test_rst_contend_mix_read_compiles(one_chip):
         grid_txns=GRID, num_engines=ENGINES, burst_beats=1,
         burst_rows=SUBLANE).compile()
     _assert_kernel(compiled)
+
+
+# The decode cell's arena: 10.7 GB of pages and weights, read in 36 KiB
+# pages by a layer's longest page table (16 x 4,224 entries) and in 1 MiB
+# weight blocks.  Neither view may make the compiler copy the arena.
+@pytest.mark.parametrize("view, grid", [("page_rows", 67584),
+                                        ("weight_rows", 256)])
+def test_rst_gather_compiles_over_the_decode_arena(one_chip, view, grid):
+    dep = deployment("deepseek-v2-lite-ep8")
+    compiled = rst_gather.lower(
+        _sds((1,), jnp.int32, one_chip), _sds((grid,), jnp.int32, one_chip),
+        _sds((SUBLANE, LANE), jnp.int32, one_chip),
+        _sds((dep.arena_rows, LANE), jnp.int32, one_chip),
+        block_rows=getattr(dep, view)).compile()
+    _assert_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_decode_arena_is_built_without_a_copy(one_chip):
+    dep = deployment("deepseek-v2-lite-ep8")
+    compiled = ops._arena_content.lower(
+        _sds((2,), jnp.uint32, one_chip), rows=dep.arena_rows).compile()
+    stats = compiled.memory_analysis()
+    assert stats.output_size_in_bytes == dep.arena_rows * LANE * 4
+    assert stats.temp_size_in_bytes < 1 << 20
 
 
 def _grid_kernel_compiles(one_chip, unit, route, lanes):
