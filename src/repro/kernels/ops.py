@@ -38,7 +38,7 @@ from repro.core.params import RSTParams
 from repro.core.rst import block_params
 from repro.core.timing_model import _grant_beats
 from repro.kernels.rst_contend import rst_contend_mix_read, rst_contend_read
-from repro.kernels.rst_gather import rst_gather
+from repro.kernels.rst_gather import blocks_per_step, rst_gather
 from repro.kernels.rst_read import LANE, SUBLANE, interpret_mode, rst_read
 from repro.kernels.rst_write import rst_write
 
@@ -552,7 +552,8 @@ def measure_gather_bandwidth(step: GatherStep) -> GatherSample:
                 n = len(call.blocks)
                 with spans.span("repro.ops.gather", kind=call.kind,
                                 blocks=n, pad=table.shape[0] - n,
-                                block_bytes=call.block_bytes):
+                                block_bytes=call.block_bytes,
+                                per_step=blocks_per_step(call.block_bytes)):
                     acc = rst_gather(count, table, acc, arena.array,
                                      block_rows=call.block_rows)
             acc.block_until_ready()

@@ -6,14 +6,27 @@ instead: weight blocks in layer order, then each sequence's KV-cache pages
 in page-table order, scattered over a page pool.  This engine reads the
 blocks a table lists.
 
-Grid step ``i`` DMAs the ``(block_rows, 128)`` int32 block at block index
-``table[i]`` of the arena.  The table and its runtime count ``n`` arrive by
-scalar prefetch, so one compiled kernel serves every table of its length;
-``block_rows`` is static (72 rows for a 36 KiB KV page, 2048 for a 1 MiB
-weight block).  Steps past ``n`` repeat the last real index, so the
-pipeline does not fetch again, and the checksum leaves them out.
+The table and its runtime count ``n`` arrive by scalar prefetch, so one
+compiled kernel serves every table of its length; ``block_rows`` is static
+(72 rows for a 36 KiB KV page, 2048 for a 1 MiB weight block).  The arena
+stays in HBM and the kernel copies the blocks itself: grid step ``i`` reads
+the ``k`` blocks ``table[i * k:(i + 1) * k]``, each a ``(block_rows, 128)``
+int32 block of the arena.  A grid step has a fixed cost of its own (about
+0.2 us on a TPU v5e, several times a 36 KiB page's 0.045 us of HBM time),
+which ``k`` spreads over its blocks: ``k`` is the largest power of two with
+``k`` blocks in at most ``STEP_BYTES``, and at least 1 (``blocks_per_step``).
+A call of 36 KiB pages reads 16 a step, one of 1 MiB weight blocks one.
 
-The checksum: the body adds the block's 8 x 128 sub-tiles into an int32
+The copies run through a two-slot VMEM ring of ``k`` blocks a slot, with
+one DMA semaphore a slot: step ``i`` starts the copies of step ``i + 1``
+into the other slot, then waits for every copy of its own slot and sums
+its blocks, so the next step's ``k`` DMAs are in flight while this step's
+blocks are summed (step 0 starts its own first).  Only the entries below
+``n`` are read: a padded entry issues no DMA and adds nothing, and the
+table past ``n`` is never looked at, so a ragged last step and the steps
+past it cost only their fixed cost.
+
+The checksum: each block's 8 x 128 sub-tiles are added into an int32
 (8, 128) accumulator, wrapping mod 2^32, so the sum is exact and the same
 in any order, and it depends on all 32 bits of every word read: a copy of
 the arena held in fewer bits gives another sum.  The kernel takes the
@@ -37,11 +50,21 @@ from repro.kernels.rst_read import LANE, SUBLANE, interpret_mode
 
 # Entries of one call's table: half of the v5e's 1 MiB of SMEM.
 MAX_TABLE = 1 << 17
+# Bytes a grid step reads at most, when its blocks are smaller than that
+# (`blocks_per_step`).  On a TPU v5e 36 KiB pages read at 170, 392, 584
+# and 606 GB/s at 1, 4, 16 and 64 a step; 1 MiB blocks at about 550 GB/s
+# at 1, 2 or 4 a step.
+STEP_BYTES = 1 << 20
 _UNROLLED_TILES = 16        # blocks of more sub-tiles loop in groups of 8
 
 
-def _index_map(i, count_ref, table_ref):
-    return table_ref[jnp.minimum(i, count_ref[0] - 1)], 0
+def blocks_per_step(block_bytes: int) -> int:
+    """Blocks a grid step reads: the largest power of two of them in
+    `STEP_BYTES`, and at least 1."""
+    k = 1
+    while 2 * k * block_bytes <= STEP_BYTES:
+        k *= 2
+    return k
 
 
 def add_tiles(acc, block_ref):
@@ -60,18 +83,45 @@ def add_tiles(acc, block_ref):
     return jax.lax.fori_loop(0, tiles // 8, group, acc)
 
 
-def _rst_gather_kernel(count_ref, table_ref, acc_in_ref, block_ref,
-                       acc_out_ref, acc_ref):
-    del table_ref  # read by the index map
+def _rst_gather_kernel(count_ref, table_ref, acc_in_ref, arena_ref,
+                       acc_out_ref, ring, sems, acc_ref):
+    _, per_step, block_rows, _ = ring.shape
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
-    def _start():
-        acc_ref[...] = acc_in_ref[...]
+    def copy(step, j):
+        """The copy of block `j` of `step` into its slot of the ring."""
+        row = pl.multiple_of(table_ref[step * per_step + j] * block_rows,
+                             SUBLANE)
+        return pltpu.make_async_copy(arena_ref.at[pl.ds(row, block_rows)],
+                                     ring.at[step % 2, j], sems.at[step % 2])
 
-    @pl.when(i < count_ref[0])
-    def _accumulate():
-        acc_ref[...] = add_tiles(acc_ref[...], block_ref)
+    def each_real_block(step, body):
+        """body(j) for each block `j` of `step` that lies below the count."""
+        def block(j, carry):
+            body(j)
+            return carry
+        real = jnp.clip(count_ref[0] - step * per_step, 0, per_step)
+        jax.lax.fori_loop(0, real, block, 0)
+
+    def start(step):
+        each_real_block(step, lambda j: copy(step, j).start())
+
+    def add(j):
+        acc_ref[...] = add_tiles(acc_ref[...], ring.at[i % 2, j])
+
+    @pl.when(i == 0)
+    def _first():
+        acc_ref[...] = acc_in_ref[...]
+        start(i)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _prefetch():
+        start(i + 1)
+
+    # A slot's copies share one semaphore, so a block is whole only once
+    # every copy of its slot has been waited for.
+    each_real_block(i, lambda j: copy(i, j).wait())
+    each_real_block(i, add)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
@@ -85,8 +135,8 @@ def rst_gather(count: jax.Array, table: jax.Array, acc: jax.Array,
 
     Args:
       count: int32[1], the real entries of `table`, at least 1.
-      table: int32[grid], block indices in units of `block_rows` rows; the
-        grid has one step per entry.
+      table: int32[entries], block indices in units of `block_rows` rows;
+        the grid has one step per `blocks_per_step` entries.
       acc: int32[8, 128], the checksum so far.
       arena: int32[rows, 128].
       block_rows: rows per block, a multiple of 8 (and of 64 above 128).
@@ -107,22 +157,28 @@ def rst_gather(count: jax.Array, table: jax.Array, acc: jax.Array,
         raise ValueError(f"block_rows={block_rows}: blocks of more than "
                          f"{_UNROLLED_TILES * SUBLANE} rows come in 64-row "
                          f"groups")
-    grid = table.shape[0]
-    if grid > MAX_TABLE:
-        raise ValueError(f"a table of {grid} entries exceeds MAX_TABLE "
+    entries = table.shape[0]
+    if entries > MAX_TABLE:
+        raise ValueError(f"a table of {entries} entries exceeds MAX_TABLE "
                          f"({MAX_TABLE}); split the call")
+    per_step = blocks_per_step(block_rows * LANE * arena.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(grid,),
+        grid=(-(-entries // per_step),),
         in_specs=[pl.BlockSpec((SUBLANE, LANE), lambda i, c, t: (0, 0)),
-                  pl.BlockSpec((block_rows, LANE), _index_map)],
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((SUBLANE, LANE), lambda i, c, t: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((SUBLANE, LANE), jnp.int32)],
+        scratch_shapes=[
+            pltpu.VMEM((2, per_step, block_rows, LANE), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((SUBLANE, LANE), jnp.int32)],
     )
     return pl.pallas_call(
         _rst_gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((SUBLANE, LANE), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="rst_gather",
         interpret=interpret_mode(),
     )(count, table, acc, arena)

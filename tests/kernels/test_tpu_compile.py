@@ -101,17 +101,24 @@ def test_rst_contend_mix_read_compiles(one_chip):
 
 
 # The decode cell's arena: 10.7 GB of pages and weights, read in 36 KiB
-# pages by a layer's longest page table (16 x 4,224 entries) and in 1 MiB
-# weight blocks.  Neither view may make the compiler copy the arena.
-@pytest.mark.parametrize("view, grid", [("page_rows", 67584),
-                                        ("weight_rows", 256)])
-def test_rst_gather_compiles_over_the_decode_arena(one_chip, view, grid):
+# pages by a layer's longest page table (16 x 4,224 entries) and its
+# smallest (one bucket), in 1 MiB weight blocks, and in 4 KiB tiles (the
+# most blocks and DMAs a grid step).  No view may make the compiler copy
+# the arena.
+@pytest.mark.parametrize("block_rows, grid", [("page_rows", 67584),
+                                              ("page_rows", 256),
+                                              ("weight_rows", 256),
+                                              (SUBLANE, 256)])
+def test_rst_gather_compiles_over_the_decode_arena(one_chip, block_rows,
+                                                   grid):
     dep = deployment("deepseek-v2-lite-ep8")
+    if isinstance(block_rows, str):
+        block_rows = getattr(dep, block_rows)
     compiled = rst_gather.lower(
         _sds((1,), jnp.int32, one_chip), _sds((grid,), jnp.int32, one_chip),
         _sds((SUBLANE, LANE), jnp.int32, one_chip),
         _sds((dep.arena_rows, LANE), jnp.int32, one_chip),
-        block_rows=getattr(dep, view)).compile()
+        block_rows=block_rows).compile()
     _assert_kernel(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
