@@ -8,8 +8,7 @@ Exit status is non-zero on any finding not in the baseline (*new*
 violations) **and** on any baseline entry no longer reproduced (*stale*
 — the baseline must shrink with the fix, keeping the pass ratchet-only).
 ``--write-baseline`` regenerates the file; ``--json`` dumps findings for
-tooling (benchmarks/run.py --lint-report times the families through
-:data:`FAMILIES`).
+tooling.
 """
 from __future__ import annotations
 

@@ -6,8 +6,7 @@ layouts to access patterns and lets the calibrated model rank them.
 
 The oracle layer (`LayoutCandidate` / `score_layouts` / `choose_layout`
 and the `advise_*` helpers) ranks array dimension orders with the
-closed-form `MemoryOracle`; `examples/autotune_layout.py` and the
-`bench_oracle_autotune` benchmark rung drive it.
+closed-form `MemoryOracle`; `examples/autotune_layout.py` drives it.
 
 The registry layer is the measured counterpart: `tune_layout(workload,
 spec, backend, budget)` searches (address policy x burst_beats x

@@ -19,7 +19,7 @@ timing model / pallas kernels on every registered memory system.
 "Experiment catalog" table (``python -m benchmarks.run --catalog``).
 
 The three old entry points are thin views over this registry:
-`ShuhaiCampaign.suite_*` (deprecated shims), `benchmarks/run.py` (CSV/JSON
+`ShuhaiCampaign.suite_*` (deprecated shims), `benchmarks/run.py` (CSV
 rows via each experiment's `summarize`), and `examples/shuhai_campaign.py`
 (flat CSV via each experiment's `flatten`).  None of them contain grid
 logic of their own.
@@ -88,12 +88,12 @@ class Experiment:
     requires_switch: bool = False
     summarize: Optional[Callable[[MemorySpec, Any], str]] = None
     flatten: Optional[Callable[[MemorySpec, Any], List[Tuple[str, str]]]] = None
-    # Historical benchmark row prefix, where it differs from `name` (keeps
-    # BENCH_*.json perf trajectories comparable across the redesign).
+    # Historical row prefix in `benchmarks/run.py`, where it differs from
+    # `name` (keeps the row names stable across the redesign).
     bench_label: Optional[str] = None
     # Spec names benchmarks/run.py times this experiment on.  None keeps
-    # the harness default (the paper's measured hbm/ddr4 pair — widening it
-    # would rename historical BENCH_*.json rows); the write/duplex family
+    # the runner's default (the paper's measured hbm/ddr4 pair — widening
+    # it would rename historical rows); the write/duplex family
     # opts into all four registered systems explicitly.
     bench_specs: Optional[Tuple[str, ...]] = None
 

@@ -153,6 +153,7 @@ def test_service_roundtrip_and_dedup():
     req = ExperimentRequest.make("layout_autotune", "hbm", quick=True)
     resp = svc.submit(req)
     assert resp.ok and isinstance(resp.result, TuneReport)
+    assert resp.result.evaluations <= resp.result.candidates
     dup = svc.submit(req)
     assert dup.coalesced and dup.result == resp.result
 

@@ -119,3 +119,54 @@ def test_remainder_on_real_8_device_mesh():
         cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "REMAINDER_OK" in out.stdout
+
+
+FOUR_DEVICE_GRID = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from repro.core import HBM, RSTParams
+from repro.core.address_mapping import policies_for
+from repro.core.timing_jax import REL_TOLERANCE, GridAxes, evaluate_grid
+from repro.launch.mesh import grid_mesh
+
+assert jax.device_count() == 4
+# Power-of-two streams, strides and windows: every lane is periodic, so
+# the whole grid runs in the compiled kernel on both sides.
+axes = GridAxes(
+    params=tuple(RSTParams(n=1 << 12, b=32, s=256 << i, w=(256 << i) * 4)
+                 for i in range(3)),
+    policies=(None,) + tuple(policies_for(HBM))[:1],
+    ops=("read", "write", "duplex"),
+    num_engines=(1, 4),
+    arbitrations=(("round_robin", 1), ("burst", 4)),
+    placements=("same_channel", "same_switch", "cross_switch"))
+one = evaluate_grid(HBM, axes)
+many = evaluate_grid(HBM, axes, mesh=grid_mesh(4))
+assert (one.output_devices, many.output_devices) == (1, 4)
+assert one.size == many.size == 216
+err = np.max(np.abs(many.gbps - one.gbps) / np.maximum(np.abs(one.gbps),
+                                                       1e-300))
+assert err <= REL_TOLERANCE, err
+np.testing.assert_array_equal(many.bound, one.bound)
+assert "numpy" not in many.lanes_by_route
+print("FOUR_DEVICE_GRID_OK", many.size)
+"""
+
+
+def test_grid_on_four_host_devices_matches_one_device():
+    """evaluate_grid over a mesh of four devices agrees with one device
+    within REL_TOLERANCE, and its outputs live on all four."""
+    import subprocess
+    import sys
+    out = subprocess.run(
+        [sys.executable, "-c", FOUR_DEVICE_GRID],
+        capture_output=True, text=True, timeout=300,
+        env={"PYTHONPATH": str(ROOT / "src"),
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "HOME": os.environ.get("HOME", str(ROOT)),
+             "JAX_PLATFORMS": "cpu"},
+        cwd=ROOT)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "FOUR_DEVICE_GRID_OK 216" in out.stdout

@@ -248,46 +248,60 @@ class TestValidation:
 
 
 class TestAcceptanceSoak:
-    def test_1000_requests_at_10pct_fault_rate(self):
-        """ISSUE 6 acceptance: 1000 mixed requests, 10% injected transient
-        faults — zero dropped, every response validated or degraded with a
-        reason, duplicates provably coalesced."""
+    """1000 mixed requests over 18 distinct keys through a fault-injected
+    sim primary with a sim fallback and full validation."""
+
+    MIX = tuple(
+        req for spec in ("hbm", "ddr4") for req in (
+            ExperimentRequest.make("fig6_address_mapping", spec, quick=True),
+            ExperimentRequest.make("table4_idle_latency", spec, n=512),
+            ExperimentRequest.make("fig4_refresh", spec, quick=True),
+            ExperimentRequest.make("fig7_locality", spec, quick=True),
+            ExperimentRequest.make("fig9_channel_contention", spec,
+                                   quick=True),
+            ExperimentRequest.make("table5_total_throughput", spec, n=2048),
+            ExperimentRequest.make("duplex_rw_sweep", spec, quick=True),
+            # Contended and mixed-engine points: validated against the
+            # contended oracles, not the homogeneous one.
+            ExperimentRequest.make("contention_scaling_sweep", spec,
+                                   quick=True),
+            ExperimentRequest.make("engine_mix_sweep", spec, quick=True),
+        ))
+
+    @pytest.mark.parametrize("rate,degraded_min,clean", [
+        (0.0, 0, True), (0.01, 0, False), (0.10, 1, False)])
+    def test_1000_requests_at_fault_rate(self, rate, degraded_min, clean):
+        """1000 mixed requests at injected fault `rate`: zero dropped,
+        every response validated or degraded with a reason, duplicates
+        provably coalesced; a clean primary retries and degrades nothing,
+        and at 10 % the faults reach the fallback."""
         try:
             register_fault_injected(
-                "sim", name="sim+soak", rate=0.10, seed=7,
+                "sim", name="sim+soak", rate=rate, seed=7,
                 kinds=("transient", "timeout", "corrupt", "unsupported"),
                 weights=(0.5, 0.2, 0.15, 0.15), timeout_s=0.2,
                 override=True)
             svc = CampaignService("sim+soak", "sim",
                                   retry=RetryPolicy(max_attempts=8),
                                   validate_fraction=1.0, seed=11)
-            mix = [
-                ExperimentRequest.make("fig6_address_mapping", quick=True),
-                ExperimentRequest.make("table4_idle_latency", n=512),
-                ExperimentRequest.make("fig4_refresh", quick=True),
-                ExperimentRequest.make("fig7_locality", quick=True),
-                ExperimentRequest.make("table5_total_throughput", n=2048),
-                ExperimentRequest.make("fig6_address_mapping", "ddr4",
-                                       quick=True),
-                ExperimentRequest.make("table4_idle_latency", "ddr4",
-                                       n=512),
-                ExperimentRequest.make("duplex_rw_sweep", "ddr4",
-                                       quick=True),
-            ]
+            mix = self.MIX
             reqs = [mix[i % len(mix)] for i in range(1000)]
             out = svc.submit_all(reqs)
             st = svc.stats
             assert len(out) == 1000 and st.dropped == 0
             assert all(r.ok for r in out)
             # Every response: oracle-validated, or degraded with a reason
-            # (validated None = plan had no oracle-checkable point; the mix
-            # above always has one).
+            # (validated None = plan had no oracle-checkable point; every
+            # request of the mix has one).
             assert all(r.validated is True
                        or (r.degraded and r.degraded_reason)
                        for r in out)
-            # Duplicates provably coalesced: 8 distinct keys executed.
+            # Duplicates provably coalesced: one execution per key.
             assert st.executed == len(mix)
-            assert st.executed < st.requests
             assert st.deduped == 1000 - len(mix)
+            assert st.degraded >= degraded_min
+            if clean:
+                assert st.retries == 0 and st.degraded == 0
+                assert st.validation_mismatches == 0
         finally:
             engine_mod._BACKEND_REGISTRY.pop("sim+soak", None)
